@@ -11,6 +11,7 @@ import time
 
 sys.path.insert(0, "src")
 
+import jax
 import numpy as np
 
 from repro.configs.starling_segment import SEGMENT_BENCH
@@ -63,7 +64,10 @@ def main():
     truth = D.brute_force_knn(union, queries, 10)
     print(f"recall@10 over {num_segments} segments: "
           f"{recall_at_k(got, truth):.3f}")
-    print(f"wall (CPU, interpret-mode kernels): {wall:.2f}s")
+    dev = jax.devices()[0]
+    mode = "interpreted" if dev.platform == "cpu" else "compiled"
+    print(f"wall ({dev.platform} {dev.device_kind}, {mode} kernels): "
+          f"{wall:.2f}s")
 
 
 if __name__ == "__main__":

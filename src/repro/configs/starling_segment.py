@@ -68,6 +68,24 @@ SEGMENT_BENCH_DEVICE = dataclasses.replace(
     cache=CacheParams(tier0_frac=0.10),
 )
 
+# the chip smoke's segment (chip_smoke.py, tests/test_chip_compile.py):
+# the paper's bigann row (Tab. 16: D=128, Λ=31, η=4 KB) held as f32, so a
+# block packs ε=6 vertices instead of the u8 row's 16. NSG graph (exact
+# kNN seed on the device's MXU), BNF shuffle, 16-subspace PQ, and a 1%
+# navigation sample; 10% of the block file packed as the tier-0 pack.
+SEGMENT_BIGANN_F32 = SegmentParams(
+    graph=GraphParams(max_degree=31, build_beam=64, algo="nsg"),
+    layout=LayoutParams(block_kb=4.0, shuffle="bnf", bnf_iters=8,
+                        gain_tau=0.001),
+    pq=PQParams(num_subspaces=16, num_centroids=256, train_iters=12),
+    nav=NavGraphParams(sample_ratio=0.01, max_degree=20, build_beam=64,
+                       search_beam=16, num_entry_points=4),
+    search=SearchParams(candidate_size=64, pruning_ratio=0.3,
+                        rs_ratio=0.5),
+    cache=CacheParams(tier0_frac=0.10),
+    metric="l2",
+)
+
 # the batched device-search knobs the benchmarks/serving dry-runs use:
 # the bench segment's Γ, paper σ, deep safety valve. DEVICE_SEARCH_WIDE
 # adds 2-wide DMA fetch (EXPERIMENTS §Perf cell 3 — fewer round trips,

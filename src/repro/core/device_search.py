@@ -429,7 +429,7 @@ def nav_entry_points(ds: DeviceSegment, queries: jnp.ndarray,
 
 def _round_stage(ds: DeviceSegment, queries: jnp.ndarray, u: jnp.ndarray,
                  metric: str, impl: str, n_expand: int, tile: int,
-                 pipeline_dma: bool, fuse_union: bool = False):
+                 pipeline_dma: bool):
     """The fused per-round fetch pipeline (DR): tier-0 probe,
     batch-scope-deduped block gather, exact rank, and the per-query
     top-``n_expand`` expansion order — one pass.
@@ -439,7 +439,6 @@ def _round_stage(ds: DeviceSegment, queries: jnp.ndarray, u: jnp.ndarray,
     hit [Q, F] i32, order [Q, n_expand]). ``impl='fused'`` runs the
     ``fused_round`` Pallas kernel (whole-batch deduped gather —
     double-buffered cold DMAs when ``pipeline_dma`` and compiled,
-    in-kernel SMEM slot-map union when ``fuse_union`` —
     idle-tile skip at the ``tile`` granularity); ``'jnp'`` is the
     pure-jnp reference with straight per-request gathers —
     bit-identical payloads (dedup only changes which gather produced a
@@ -452,7 +451,7 @@ def _round_stage(ds: DeviceSegment, queries: jnp.ndarray, u: jnp.ndarray,
             queries, u, ds.block_of, ds.hot_slot_of, ds.hot_vecs,
             ds.hot_vid, ds.hot_nbrs, ds.vecs, ds.vid, ds.nbrs,
             n_expand, metric=metric, bq=tile,
-            pipeline_dma=pipeline_dma, fuse_union=fuse_union)
+            pipeline_dma=pipeline_dma)
     else:
         from repro.kernels import ref
         dd, vid, nbrs, hit, order = ref.fused_round_ref(
@@ -515,8 +514,7 @@ def _block_search_loop(ds: DeviceSegment, queries: jnp.ndarray, lut,
                        compact_frac: float = 0.0, trace: bool = False,
                        pipeline_dma: bool = False,
                        round_tile_cap: int = 0,
-                       speculate: bool = False,
-                       fuse_union: bool = False):
+                       speculate: bool = False):
     """The batched best-first block search from a given carried state.
 
     ``state`` = (cand_id, cand_key, open_key, visited, res_id, res_key,
@@ -568,11 +566,7 @@ def _block_search_loop(ds: DeviceSegment, queries: jnp.ndarray, lut,
     bit-identical to ``speculate=False``, and the loop jaxpr without
     the knob is unchanged. The final round's staged prediction is
     dropped unconsumed (modeled as issued at the consume boundary —
-    a search that ends never issues it, so it is not wasted DMA).
-
-    ``fuse_union`` (jit-static) selects the in-kernel SMEM slot-map
-    union of the round kernel (``kernels.tier0_fetch.gather_union``)
-    over the two-pass pass-1 union — bit-identical either way."""
+    a search that ends never issues it, so it is not wasted DMA)."""
     qn = queries.shape[0]
     eps = ds.vid.shape[1]
     fw = max(fetch_width, 1)
@@ -652,7 +646,7 @@ def _block_search_loop(ds: DeviceSegment, queries: jnp.ndarray, lut,
         # block union, rank, and order expansions — one fused pass
         vid, nbrs, dd, hit, order = _round_stage(
             ds, q_r, u, metric, fetch_impl, n_expand, tile,
-            pipeline_dma, fuse_union)
+            pipeline_dma)
         hot = hit.astype(bool) & f_active
         cold = f_active & ~hot
         joined, joined_x = _dedup_joins(b, cold, tile)       # [Q, F]
@@ -878,7 +872,7 @@ def device_anns(ds: DeviceSegment, queries: jnp.ndarray,
         compact_frac=p.compact_frac, trace=p.trace_rounds,
         pipeline_dma=p.pipeline_dma,
         round_tile_cap=p.round_tile_cap,
-        speculate=p.speculate, fuse_union=p.fuse_union)
+        speculate=p.speculate)
     if p.speculate:
         (_, _, _, _, res_id, res_key, io, t0, hops, saved, saved_x,
          spec_h, spec_w, t) = state
@@ -918,14 +912,18 @@ def merge_shard_topk(gids: jnp.ndarray, gd: jnp.ndarray,
             jnp.take_along_axis(flat_d, order, axis=1))
 
 
-def stack_segments(segments) -> DeviceSegment:
+def stack_segments(segments, sharding) -> DeviceSegment:
     """Stack same-shape segment shards along a new leading axis — the
     [W, ...] tree ``make_search_step``/the mesh router shard over the
     ``model`` axis (one shard per rank; replicas are repeated
     entries). All shards must agree on every array's shape and dtype
     so a restack after a rebalance reuses the same compiled
     executable (the mesh analogue of ``repack_tier0``'s same-shape
-    in-place swap)."""
+    in-place swap).
+
+    ``sharding`` (a ``NamedSharding`` splitting the leading axis) places
+    each shard straight on its own device: the stack is never gathered
+    onto one device, so each device holds only its own shard."""
     if not segments:
         raise ValueError("stack_segments needs at least one shard")
     first = segments[0]
@@ -938,7 +936,14 @@ def stack_segments(segments) -> DeviceSegment:
                     f"{b.shape}/{b.dtype}, shard 0 has "
                     f"{a.shape}/{a.dtype} — mesh shards must be "
                     "shape-identical (pad segments to a common size)")
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *segments)
+
+    def place(*xs):
+        return jax.make_array_from_callback(
+            (len(xs),) + xs[0].shape, sharding,
+            lambda idx: jnp.stack(xs[idx[0]]))
+
+    return jax.tree.map(place, *segments)
+
 
 def make_search_step(mesh, rules, *,
                      n_local: int = 1 << 21, dim: int = 128,
@@ -965,10 +970,6 @@ def make_search_step(mesh, rules, *,
     outputs — the mesh-level QPS fold in ``benchmarks/paper_tables.py``
     consumes exactly these."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:                    # older jax releases
-        from jax.experimental.shard_map import shard_map
 
     if search is None:
         search = DeviceSearchParams(candidates=64, max_hops=128)
@@ -1041,11 +1042,8 @@ def make_search_step(mesh, rules, *,
                 r.spec_hits[:, None] * col,
                 r.spec_wasted[:, None] * col)
 
-    import inspect
-    flag = ("check_vma" if "check_vma"
-            in inspect.signature(shard_map).parameters else "check_rep")
-    fn = shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, **{flag: False})
+    fn = jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn, (seg_specs, q_specs)
 
 
@@ -1131,7 +1129,7 @@ def device_range_search(ds: DeviceSegment, queries: jnp.ndarray,
             compact_frac=p.compact_frac, trace=False,
             pipeline_dma=p.pipeline_dma,
             round_tile_cap=p.round_tile_cap,
-            speculate=p.speculate, fuse_union=p.fuse_union)
+            speculate=p.speculate)
         if p.speculate:
             (_, _, _, visited, res_id, res_key, io, t0, hops, saved,
              saved_x, sh_r, sw_r, t) = state

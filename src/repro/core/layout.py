@@ -62,17 +62,27 @@ class BlockLayout:
 
 
 def _from_block_of(block_of: np.ndarray, rho: int, eps: int) -> BlockLayout:
+    """Each block's vertices in ascending id order, in slots 0, 1, ..."""
     n = block_of.shape[0]
     blocks = np.full((rho, eps), -1, np.int32)
+    order = np.argsort(block_of, kind="stable")
+    b = block_of[order]
+    slot = np.arange(n) - np.searchsorted(b, b, side="left")
+    blocks[b, slot] = order
     slot_of = np.empty(n, np.int32)
-    fill = np.zeros(rho, np.int32)
-    for u in range(n):
-        b = block_of[u]
-        blocks[b, fill[b]] = u
-        slot_of[u] = fill[b]
-        fill[b] += 1
+    slot_of[order] = slot
     return BlockLayout(blocks=blocks, block_of=block_of.astype(np.int32),
                        slot_of=slot_of)
+
+
+def _block_counts(src: np.ndarray, blk: np.ndarray, rho: int):
+    """Edges ``src`` -> a neighbor in block ``blk`` (``src`` ascending) ->
+    (u, b, count) of each distinct (vertex, block) pair, ordered by
+    vertex, then count descending, then block id."""
+    key = np.unique(src.astype(np.int64) * rho + blk, return_counts=True)
+    u, b = np.divmod(key[0], rho)
+    order = np.lexsort((b, -key[1], u))
+    return u[order], b[order], key[1][order]
 
 
 def _neighbor_keys(g: Graph) -> np.ndarray:
@@ -178,38 +188,37 @@ def layout_bnf(g: Graph, eps: int, iters: int = 8, tau: float = 0.01,
     starts = np.searchsorted(sym[:, 0], np.arange(n + 1))
     sym_dst = sym[:, 1].astype(np.int32)
 
+    src = sym[:, 0]
+
     for _ in range(iters):
+        # every vertex's neighbor blocks under the snapshot, most
+        # neighbors first, ties to the lower block id — fixed for the
+        # whole round, since the snapshot is
+        cand_u, cand_b, cnt = _block_counts(src, prev[sym_dst], rho)
+        cstarts = np.searchsorted(cand_u, np.arange(n + 1)).tolist()
         if gain_order:
-            gains = np.zeros(n, np.int32)
-            for u in range(n):
-                row = prev[sym_dst[starts[u]:starts[u + 1]]]
-                if row.size:
-                    gains[u] = np.bincount(row).max(initial=0)
+            gains = np.zeros(n, np.int64)
+            has = np.diff(cstarts) > 0
+            gains[has] = cnt[np.asarray(cstarts[:-1])[has]]
             order = np.argsort(-gains, kind="stable")
         else:
             order = np.argsort(prev, kind="stable")
-        new = np.full(n, -1, np.int32)
-        fill = np.zeros(rho, np.int32)
+        cand_b = cand_b.tolist()
+        new = [-1] * n
+        fill = [0] * rho
         spill_ptr = 0
-        for u in order:
-            row = prev[sym_dst[starts[u]:starts[u + 1]]]
-            placed = False
-            if row.size:
-                cnt = np.bincount(row)
-                cand = np.argsort(-cnt, kind="stable")
-                for b in cand:
-                    if cnt[b] == 0:
-                        break
-                    if fill[b] < eps:
-                        new[u] = b
-                        fill[b] += 1
-                        placed = True
-                        break
-            if not placed:                       # lines 13–14: spill
+        for u in order.tolist():
+            for j in range(cstarts[u], cstarts[u + 1]):
+                b = cand_b[j]
+                if fill[b] < eps:
+                    break
+            else:                                # lines 13–14: spill
                 while fill[spill_ptr] >= eps:
                     spill_ptr += 1
-                new[u] = spill_ptr
-                fill[spill_ptr] += 1
+                b = spill_ptr
+            new[u] = b
+            fill[b] += 1
+        new = np.asarray(new, np.int32)
         layout = _from_block_of(new, rho, eps)
         cur = overlap_ratio(g, layout, keys)
         gain = cur - history[-1]

@@ -343,10 +343,9 @@ class DeviceSearchParams:
     #                               bit-identical on or off
     pipeline_dma: bool = True     # double-buffer the fused kernel's
     #                               cold-block gather (make_async_copy
-    #                               two-slot schedule) on compiled runs;
-    #                               interpret always takes the straight-
-    #                               line fallback, and the jnp fetch
-    #                               stage ignores it. Payloads are
+    #                               two-slot schedule); off, one block
+    #                               copy is in flight at a time. The jnp
+    #                               fetch stage ignores it. Payloads are
     #                               bit-identical on or off — only the
     #                               DMA schedule (and the cost model's
     #                               max(dma, compute) overlap pricing,
@@ -373,14 +372,6 @@ class DeviceSearchParams:
     #                               spec_hits/spec_wasted accounting
     #                               (and the CostModel's speculative
     #                               overlap pricing) move.
-    fuse_union: bool = True       # fuse the pass-1 sorted-unique block
-    #                               union into the round kernel's pass 2
-    #                               (SMEM-staged slot map) instead of
-    #                               running it as jnp ops between the
-    #                               pallas_calls. Payload-bit-identical
-    #                               either way — the union math is the
-    #                               shared kernels.dedup formulation in
-    #                               both placements.
 
     def __post_init__(self):
         if self.k < 1 or self.candidates < self.k:

@@ -15,5 +15,4 @@
 # oracle in ref.py and the jit'd dispatch wrapper in ops.py.
 from repro.kernels.dedup import join_mask, sorted_unique_ranks
 from repro.kernels.ops import (pairwise_l2, pq_adc_batch, block_rank,
-                               tier0_rank, fused_round, round_tile,
-                               set_interpret, interpret_default)
+                               tier0_rank, fused_round, round_tile)

@@ -21,10 +21,12 @@ BQ = 128
 def _rank_kernel(q_ref, t_ref, d_ref, i_ref, *, top_m: int, metric: str):
     q = q_ref[...].astype(jnp.float32)              # [BQ, D]
     t = t_ref[...].astype(jnp.float32)              # [BQ, eps, D]
+    # one query per tile row: a multiply-reduce, not a batched matmul
+    # (Mosaic has no lowering for a dot batched over the query axis)
+    dot = jnp.sum(q[:, None, :] * t, axis=-1)
     if metric == "ip":
-        d = -jnp.einsum("qd,qed->qe", q, t)
+        d = -dot
     else:
-        dot = jnp.einsum("qd,qed->qe", q, t)
         tt = jnp.sum(t * t, axis=-1)
         qq = jnp.sum(q * q, axis=-1, keepdims=True)
         d = jnp.maximum(tt + qq - 2.0 * dot, 0.0)
@@ -40,8 +42,7 @@ def _rank_kernel(q_ref, t_ref, d_ref, i_ref, *, top_m: int, metric: str):
 
 
 def block_topk(queries: jnp.ndarray, tiles: jnp.ndarray, top_m: int,
-               metric: str = "l2", interpret: bool = True,
-               bq: int = BQ):
+               metric: str = "l2", *, interpret: bool, bq: int = BQ):
     """queries [Q, D]; tiles [Q, eps, D] -> (dists [Q, eps] f32,
     top_idx [Q, top_m] int32)."""
     qn, d = queries.shape

@@ -11,10 +11,7 @@ hand-roll the same argsort/cumsum idiom; this module is the single
 implementation so kernel and reference accounting cannot drift.
 
 All helpers are plain jnp and run unchanged inside ``jit`` or
-eagerly; ``union_slot_map`` additionally lowers inside a Pallas
-kernel body (no sort/scatter), which is how the fused-union round
-kernel (DESIGN.md §9) computes the same union without the two
-host-visible pass-1 intermediates.
+eagerly.
 """
 from __future__ import annotations
 
@@ -49,44 +46,6 @@ def sorted_unique_ranks(flat: jnp.ndarray):
     req_rank = jnp.zeros((r,), jnp.int32).at[sort_idx].set(
         rank.astype(jnp.int32))
     return uniq, req_rank
-
-
-def union_slot_map(flat: jnp.ndarray):
-    """Sort-free ``sorted_unique_ranks`` twin for in-kernel union fusion.
-
-    Bit-identical to :func:`sorted_unique_ranks` — same ascending
-    ``uniq`` with 0 placeholders past the distinct count, same
-    ``rank`` slot map — but formulated as O(R^2) branch-free
-    comparisons instead of argsort+scatter, so it lowers inside a
-    Pallas kernel body (Mosaic has no stable sort / scatter
-    primitive).  Per distinct key:
-
-      * ``first[j]``: no earlier flat slot carries an equal key
-        (the "first requester" that pays the gather);
-      * ``rank[j]``: number of distinct keys strictly smaller than
-        ``flat[j]`` — equals the cumsum-of-first rank in sorted
-        order, duplicate slots share their group's rank;
-      * ``uniq[r]``: the key whose rank is ``r`` (one-hot select and
-        sum); ranks past the distinct count select nothing and keep
-        the 0 placeholder, matching the scatter zeros.
-
-    Assumes non-negative keys (block ids); R up to a few hundred
-    keeps the R^2 masks comfortably in VMEM.
-    """
-    r = flat.shape[0]
-    # 2-D iotas (TPU requires >= 2-D); axis 0 = i (earlier/selector),
-    # axis 1 = j (slot under test)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (r, r), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (r, r), 1)
-    eq = flat[:, None] == flat[None, :]           # eq[i, j]
-    first = ~jnp.any(eq & (ii < jj), axis=0)      # no earlier equal
-    smaller = flat[:, None] < flat[None, :]       # flat[i] < flat[j]
-    rank = jnp.sum((first[:, None] & smaller).astype(jnp.int32),
-                   axis=0)                        # distinct-smaller count
-    sel = first[None, :] & (rank[None, :] == ii)  # sel[r, j]
-    uniq = jnp.sum(jnp.where(sel, flat[None, :], 0),
-                   axis=1).astype(flat.dtype)
-    return uniq, rank.astype(jnp.int32)
 
 
 def join_mask(keys: jnp.ndarray) -> jnp.ndarray:

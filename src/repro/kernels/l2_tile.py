@@ -31,9 +31,8 @@ def _l2_kernel(q_ref, x_ref, o_ref, *, metric: str):
         o_ref[...] = jnp.maximum(qq + xx - 2.0 * dot, 0.0)
 
 
-def l2_tile(q: jnp.ndarray, x: jnp.ndarray, metric: str = "l2",
-            interpret: bool = True, bq: int = BQ, bn: int = BN
-            ) -> jnp.ndarray:
+def l2_tile(q: jnp.ndarray, x: jnp.ndarray, metric: str = "l2", *,
+            interpret: bool, bq: int = BQ, bn: int = BN) -> jnp.ndarray:
     """[Q, D] x [N, D] -> [Q, N] (f32). Q % bq == 0 and N % bn == 0 is
     handled by padding in ops.pairwise_l2."""
     qn, d = q.shape

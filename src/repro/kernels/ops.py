@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute with ``interpret=True`` (the
-kernel body runs in Python under the Pallas interpreter — bit-faithful to
-the TPU lowering semantics); on TPU ``set_interpret(False)`` compiles the
-real Mosaic kernels. Wrappers pad inputs to tile multiples and strip the
-padding from outputs.
+The kernel mode follows the platform a call is lowered for: on the CPU
+the kernels run under the Pallas interpreter (``interpret=True``), on a
+TPU they are compiled by Mosaic — a kernel that does not lower there
+raises, it never falls back. ``interpret=`` pins the mode explicitly
+(tests use it to run either side). Wrappers pad inputs to tile
+multiples and strip the padding from outputs.
 """
 from __future__ import annotations
 
@@ -18,16 +19,16 @@ from repro.kernels import l2_tile as _l2
 from repro.kernels import pq_adc as _adc
 from repro.kernels import tier0_fetch as _t0
 
-_INTERPRET = True
 
-
-def set_interpret(flag: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = flag
-
-
-def interpret_default() -> bool:
-    return _INTERPRET
+def _call(kernel, interpret, *args):
+    """``kernel(*args, interpret=...)``: interpreted where the call runs
+    on the CPU, compiled everywhere else — unless ``interpret`` pins
+    the mode."""
+    if interpret is not None:
+        return kernel(*args, interpret=interpret)
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(kernel, interpret=True),
+        default=functools.partial(kernel, interpret=False))
 
 
 def _pad_rows(a: jnp.ndarray, mult: int) -> jnp.ndarray:
@@ -44,28 +45,23 @@ def pairwise_l2(q: jnp.ndarray, x: jnp.ndarray, metric: str = "l2",
                 interpret: bool = None, bq: int = None, bn: int = None
                 ) -> jnp.ndarray:
     """[Q, D] x [N, D] -> [Q, N] distances via the l2_tile kernel."""
-    interpret = _INTERPRET if interpret is None else interpret
     bq = bq or min(_l2.BQ, max(8, q.shape[0]))
     bn = bn or min(_l2.BN, max(8, x.shape[0]))
     qp, xp = _pad_rows(q, bq), _pad_rows(x, bn)
-    out = _l2.l2_tile(qp, xp, metric=metric, interpret=interpret,
-                      bq=bq, bn=bn)
-    out = out[: q.shape[0], : x.shape[0]]
-    if metric == "l2":
-        return out
-    # padded base rows are zero vectors -> -0.0 for ip; harmless, sliced.
-    return out
+    out = _call(functools.partial(_l2.l2_tile, metric=metric, bq=bq,
+                                  bn=bn), interpret, qp, xp)
+    # padded base rows are zero vectors (-0.0 for ip); sliced off
+    return out[: q.shape[0], : x.shape[0]]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
 def pq_adc_batch(codes: jnp.ndarray, luts: jnp.ndarray,
                  interpret: bool = None, bn: int = None) -> jnp.ndarray:
     """codes [N, M] uint8 x luts [B, M, K] -> [B, N] ADC distances."""
-    interpret = _INTERPRET if interpret is None else interpret
     bn = bn or min(_adc.BN, max(8, codes.shape[0]))
     cp = _pad_rows(codes, bn)
-    out = _adc.pq_adc(cp, luts.astype(jnp.float32), interpret=interpret,
-                      bn=bn)
+    out = _call(functools.partial(_adc.pq_adc, bn=bn), interpret, cp,
+                luts.astype(jnp.float32))
     return jnp.moveaxis(out, 0, 1)[:, : codes.shape[0]]
 
 
@@ -76,12 +72,12 @@ def block_rank(queries: jnp.ndarray, tiles: jnp.ndarray, top_m: int,
                bq: int = None):
     """queries [Q, D] x gathered tiles [Q, eps, D] ->
     (dists [Q, eps], top_idx [Q, top_m])."""
-    interpret = _INTERPRET if interpret is None else interpret
     bq = bq or min(_bt.BQ, max(8, queries.shape[0]))
     qp = _pad_rows(queries, bq)
     tp = _pad_rows(tiles, bq)
-    d, idx = _bt.block_topk(qp, tp, top_m, metric=metric,
-                            interpret=interpret, bq=bq)
+    d, idx = _call(functools.partial(_bt.block_topk, top_m=top_m,
+                                     metric=metric, bq=bq),
+                   interpret, qp, tp)
     return d[: queries.shape[0]], idx[: queries.shape[0]]
 
 
@@ -99,55 +95,43 @@ def round_tile(qn: int, cap: int = 0) -> int:
 
 @functools.partial(jax.jit,
                    static_argnames=("n_expand", "metric", "interpret",
-                                    "bq", "pipeline_dma", "fuse_union",
-                                    "_force_dma"))
+                                    "bq", "pipeline_dma"))
 def fused_round(queries: jnp.ndarray, u: jnp.ndarray,
                 block_of: jnp.ndarray, hot_slot_of: jnp.ndarray,
                 hot_vecs: jnp.ndarray, hot_vid: jnp.ndarray,
                 hot_nbrs: jnp.ndarray, vecs: jnp.ndarray,
                 vid: jnp.ndarray, nbrs: jnp.ndarray, n_expand: int,
                 metric: str = "l2", interpret: bool = None,
-                bq: int = None, pipeline_dma: bool = False,
-                fuse_union: bool = False, _force_dma: bool = False):
+                bq: int = None, pipeline_dma: bool = True):
     """Fused per-round fetch pipeline of the batched device search:
-    whole-batch sorted-unique dedup (pass 1, fused into the gather
-    kernel's SMEM slot map when ``fuse_union`` is set),
-    once-per-distinct-block gather — double-buffered when
-    ``pipeline_dma`` is on and the kernels compile (pass 2a) — then
-    per-tile broadcast + exact distances + per-query top-``n_expand``
-    expansion order (pass 2b). Padded query rows carry ``u = -1``
-    (converged), so all-pad tiles take the rank kernel's skip path;
-    their outputs are sliced off."""
-    interpret = _INTERPRET if interpret is None else interpret
+    whole-batch sorted-unique dedup (pass 1), once-per-distinct-
+    block DMA gather from the HBM store (pass 2a; two-slot schedule
+    when ``pipeline_dma``), tier-0 select and broadcast, then per-tile
+    exact distances + per-query top-``n_expand`` expansion order
+    (pass 2b). Padded query rows carry ``u = -1`` (converged), so
+    all-pad tiles take the rank kernel's skip path; their outputs are
+    sliced off."""
     bq = bq or round_tile(queries.shape[0])
     qp = _pad_rows(queries, bq)
     pad = (-u.shape[0]) % bq
     up = u if pad == 0 else jnp.pad(u, ((0, pad), (0, 0)),
                                     constant_values=-1)
-    outs = _t0.fused_round(qp, up, block_of, hot_slot_of, hot_vecs,
-                           hot_vid, hot_nbrs, vecs, vid, nbrs,
-                           n_expand, metric=metric,
-                           interpret=interpret, bq=bq,
-                           pipeline_dma=pipeline_dma,
-                           fuse_union=fuse_union,
-                           _force_dma=_force_dma)
+    outs = _call(functools.partial(_t0.fused_round, n_expand=n_expand,
+                                   metric=metric, bq=bq,
+                                   pipeline_dma=pipeline_dma),
+                 interpret, qp, up, block_of, hot_slot_of, hot_vecs,
+                 hot_vid, hot_nbrs, vecs, vid, nbrs)
     return tuple(o[: queries.shape[0]] for o in outs)
 
 
-@functools.partial(jax.jit, static_argnames=("metric", "interpret", "bq"))
+@functools.partial(jax.jit, static_argnames=("metric", "interpret"))
 def tier0_rank(queries: jnp.ndarray, blocks: jnp.ndarray,
                hot_slot_of: jnp.ndarray, hot_vecs: jnp.ndarray,
                cold_vecs: jnp.ndarray, metric: str = "l2",
-               interpret: bool = None, bq: int = None):
-    """Fused tier-0 probe + gather + rank (the device fetch stage):
-    queries [Q, D] x target blocks [Q, F] -> (dists [Q, F*eps] over the
-    gathered tiles, hit [Q, F] tier-0 mask). Padded rows probe block 0;
-    their outputs are sliced off."""
-    interpret = _INTERPRET if interpret is None else interpret
-    bq = bq or min(_t0.BQ, max(8, queries.shape[0]))
-    qp = _pad_rows(queries, bq)
-    bp = _pad_rows(blocks, bq)
-    d, hit = _t0.tier0_fetch_rank(qp, bp, hot_slot_of, hot_vecs,
-                                  cold_vecs, metric=metric,
-                                  interpret=interpret, bq=bq)
-    return d[: queries.shape[0]], hit[: queries.shape[0]]
+               interpret: bool = None):
+    """Tier-0 probe + block gather + rank: queries [Q, D] x target
+    blocks [Q, F] -> (dists [Q, F*eps] over the gathered tiles, hit
+    [Q, F] tier-0 mask)."""
+    return _call(functools.partial(_t0.tier0_fetch_rank, metric=metric),
+                 interpret, queries, blocks, hot_slot_of, hot_vecs,
+                 cold_vecs)

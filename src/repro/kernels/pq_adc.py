@@ -37,8 +37,8 @@ def _adc_kernel(codes_ref, luts_ref, o_ref, *, num_centroids: int):
         preferred_element_type=jnp.float32)          # [BN, B]
 
 
-def pq_adc(codes: jnp.ndarray, luts: jnp.ndarray,
-           interpret: bool = True, bn: int = BN) -> jnp.ndarray:
+def pq_adc(codes: jnp.ndarray, luts: jnp.ndarray, *,
+           interpret: bool, bn: int = BN) -> jnp.ndarray:
     """codes [N, M] uint8, luts [B, M, K] f32 -> [N, B] distances."""
     n, m = codes.shape
     b, m2, k = luts.shape

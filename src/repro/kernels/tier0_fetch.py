@@ -1,56 +1,44 @@
-"""Fused tier-0 probe + gather + rank kernels (DESIGN.md §3.2, §4, §8).
+"""Block gather + rank kernels of the device search's fetch stage
+(DESIGN.md §3.2, §4, §8).
 
-Two generations of the device search's fetch stage live here:
+The cold block store and the tier-0 hot pack stay in HBM
+(``memory_space=pl.ANY``): at segment scale (1M f32 vectors of 128 dims
+is 512 MB of cold store) neither fits VMEM. Every kernel reads block
+ids as SMEM scalars and copies whole blocks with ``make_async_copy`` —
+no kernel indexes a vector with a vector, so all of them lower with
+Mosaic.
 
-``tier0_fetch_rank`` (ISSUE 3) — for the F block ids one round trip
-targets per query, probe the tier-0 hot-slot map, gather each block's
-vector tile from the VMEM-resident hot pack on a hit or from the HBM
-block store on a miss (the DMA the cost model prices), and exact-rank
-all F*eps resident vertices against the query — one kernel, so hot hits
-never round-trip through HBM between probe and rank.
-
-``fused_round`` (ISSUE 4, reworked batch-scope in ISSUE 8) — the whole
-per-round fetch pipeline of the *divergence-aware batched* search as a
-two-pass batch-scope pipeline:
+``fused_round`` — the per-round fetch pipeline of the *divergence-aware
+batched* search:
 
   * **pass 1** (plain jnp, traced into the surrounding jit): derive the
     target blocks from the picked candidates and union them into the
-    whole-batch sorted-unique block list via the shared
-    ``kernels.dedup`` helper — one list for ALL Q x F requests, not one
-    per kernel query tile — plus the flat-slot -> unique-rank map every
-    query tile carries into pass 2 (an SMEM-sized i32 [BQ, F] block);
-  * **pass 2a** (``gather_unique``, grid over unique-block chunks):
-    copy each distinct block's cold payload (vectors / ids / neighbor
-    rows) out of the HBM block store exactly ONCE batch-wide — the
-    modeled DMAs. When ``pipeline_dma`` is on (and the kernel is
-    compiled, not interpreted) the copies run the classic Pallas
-    ``make_async_copy`` double buffer: block j+1's HBM->VMEM copy is
-    in flight while block j's tile is written, and across grid steps
-    the Pallas pipeline prefetches chunk i+1 during chunk i's compute
-    — the overlap ``CostModel`` prices as ``max(dma, compute)``.
-    Under ``interpret=True`` a straight-line fallback gathers the
-    chunk in one vector select — bit-identical payloads either way;
-  * **pass 2b** (``_rank_kernel``, grid over query tiles): probe the
-    tier-0 hot-slot map for the unique list, select each distinct
-    block's tile from the VMEM hot pack (hit — no DMA happened) or
-    the pass-2a cold copy, broadcast to requesting slots through the
-    rank map, compute exact distances, and per-query
-    top-``n_expand``-rank the masked selection key. A tile whose
-    queries are all converged (every ``u`` slot is -1 — what
-    active-query compaction clusters) skips the broadcast+rank body
-    entirely and writes masked sentinels.
-
-ISSUE 9 fuses pass 1 into pass 2a: ``gather_union`` computes the same
-whole-batch union INSIDE the gather kernel via the sort-free
-``dedup.union_slot_map`` twin, stages the flat-slot -> unique-rank map
-through SMEM scratch, and emits the identical five pass-2b inputs — so
-the first cold DMA (double-buffered or speculative) can issue without a
-host-visible pass-1 boundary. ``fused_round(fuse_union=True)`` selects
-it; the two-pass path stays as the bit-identity oracle twin.
+    whole-batch sorted-unique block list via ``dedup.sorted_unique_ranks``
+    — one list for ALL Q x F requests — plus the flat-slot -> unique-rank
+    map;
+  * **pass 2a** (``gather_blocks``, grid over unique-block chunks, block
+    ids scalar-prefetched into SMEM): copy each distinct block's vector
+    tile out of the HBM block store exactly ONCE batch-wide — the
+    modeled DMAs. The block's ids and neighbor rows, whose minor
+    dimensions (eps, Lam) are narrower than a 128-lane tile and so
+    cannot be sliced by a Mosaic DMA, are gathered by XLA.
+    ``pipeline_dma`` runs the two-slot ``make_async_copy`` schedule
+    (block j+1's copy is in flight while block j's lands); off, each
+    copy starts and finishes before the next one starts. Payloads are
+    identical either way;
+  * **select + broadcast** (plain jnp): probe the tier-0 hot-slot map for
+    the unique list, take each distinct block's payload from the hot
+    pack (hit) or the pass-2a copy, and broadcast it to the requesting
+    slots through the rank map;
+  * **pass 2b** (``_rank_kernel``, grid over query tiles): exact
+    distances and the per-query top-``n_expand`` expansion order by
+    iterative masked argmin (stable-argsort tie order). A tile whose
+    queries are all converged (every ``u`` slot is -1 — what active-query
+    compaction clusters) skips the body and writes masked sentinels; the
+    tile's liveness reaches the kernel as a scalar-prefetched flag.
 
 Distances use the same f32 sum-of-squared-differences (or negated IP)
-form as the pure-jnp fetch stage, keeping the fused and reference
-implementations bit-identical; the hot pack holds exact copies of the
+form as the pure-jnp fetch stage; the hot pack holds exact copies of the
 packed blocks, so neither tier-0 budget nor dedup scope ever changes
 (ids, dists) — only which source tier served a tile and which counter
 (``io`` / ``tier0_hits`` / ``dedup_saved``) a touch lands in.
@@ -62,288 +50,187 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import dedup
 
 BQ = 128   # query-tile size of the rank pass
-RB = 128   # unique-block chunk size of the cold-gather pass
+RB = 128   # block chunk size of the gather pass
 
 
-# -------------------------------------------- pass 2a: unique cold gather
+# ------------------------------------------------ pass 2a: block gather
 
-def _gather_unique_kernel(uniq_ref, vecs_ref, vid_ref, nbrs_ref,
-                          tv_ref, ti_ref, tn_ref):
-    """Straight-line chunk gather (the ``interpret=True`` fallback and
-    the ``pipeline_dma=False`` path): copy the chunk's distinct blocks
-    out of the cold store in one vector gather."""
-    u = uniq_ref[...]                             # [RB] distinct blocks
-    tv_ref[...] = vecs_ref[...][u]
-    ti_ref[...] = vid_ref[...][u]
-    tn_ref[...] = nbrs_ref[...][u]
+def _dma_gather(block_id, rb, srcs, dsts, sems, pipeline: bool):
+    """Copy ``rb`` whole blocks out of the HBM stores ``srcs`` into rows
+    0..rb-1 of the VMEM tiles ``dsts``; ``block_id(j)`` is row j's block
+    id, read as an SMEM scalar. ``pipeline`` runs the two-slot schedule:
+    while block j's copies complete, block j+1's are already in flight
+    on the other semaphore slot. Off, one block is in flight at a time.
+    The payload is identical either way; only the schedule differs."""
 
+    def copies(slot, j):
+        blk = block_id(j)
+        return [pltpu.make_async_copy(src.at[pl.ds(blk, 1)],
+                                      dst.at[pl.ds(j, 1)], sems.at[slot, a])
+                for a, (src, dst) in enumerate(zip(srcs, dsts))]
 
-def _double_buffered_gather(u, vecs_ref, vid_ref, nbrs_ref,
-                            tv_ref, ti_ref, tn_ref,
-                            vscr, iscr, nscr, sems):
-    """The classic two-slot ``make_async_copy`` schedule, shared by the
-    chunked and fused-union DMA kernels: while distinct block j's
-    payload is written to the output tile, the HBM copies of block
-    j+1's vector / id / neighbor rows are already in flight into the
-    other scratch slot. Payload-identical to a straight-line gather;
-    only the schedule differs."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    rb = u.shape[0]
-
-    def cold_dma(slot, j):
-        blk = u[j]
-        return (pltpu.make_async_copy(vecs_ref.at[pl.ds(blk, 1)],
-                                      vscr.at[slot], sems.at[slot, 0]),
-                pltpu.make_async_copy(vid_ref.at[pl.ds(blk, 1)],
-                                      iscr.at[slot], sems.at[slot, 1]),
-                pltpu.make_async_copy(nbrs_ref.at[pl.ds(blk, 1)],
-                                      nscr.at[slot], sems.at[slot, 2]))
-
-    for c in cold_dma(0, 0):                      # warm up slot 0
-        c.start()
+    if pipeline:
+        for c in copies(0, 0):                    # warm up slot 0
+            c.start()
 
     def body(j, carry):
         slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < rb)
-        def _start_next():                        # overlap j's write
-            for c in cold_dma(1 - slot, j + 1):
+        if pipeline:
+            @pl.when(j + 1 < rb)
+            def _start_next():
+                for c in copies(1 - slot, j + 1):
+                    c.start()
+        else:
+            for c in copies(slot, j):
                 c.start()
-
-        for c in cold_dma(slot, j):
+        for c in copies(slot, j):
             c.wait()
-        tv_ref[pl.ds(j, 1)] = vscr[slot]
-        ti_ref[pl.ds(j, 1)] = iscr[slot]
-        tn_ref[pl.ds(j, 1)] = nscr[slot]
         return carry
 
     jax.lax.fori_loop(0, rb, body, 0)
 
 
-def _gather_unique_dma_kernel(uniq_ref, vecs_ref, vid_ref, nbrs_ref,
-                              tv_ref, ti_ref, tn_ref,
-                              vscr, iscr, nscr, sems):
-    """Double-buffered cold gather over a precomputed unique chunk:
-    across grid steps the Pallas pipeline additionally prefetches chunk
-    i+1's operands during chunk i, so the fetch overlaps the rank
-    pass's distance+expansion compute."""
-    _double_buffered_gather(uniq_ref[...], vecs_ref, vid_ref, nbrs_ref,
-                            tv_ref, ti_ref, tn_ref,
-                            vscr, iscr, nscr, sems)
+def _gather_kernel(idx_ref, *refs, rb: int, pipeline: bool):
+    n = (len(refs) - 1) // 2
+    srcs, dsts, sems = refs[:n], refs[n:2 * n], refs[2 * n]
+    base = pl.program_id(0) * rb
+    _dma_gather(lambda j: idx_ref[base + j], rb, srcs, dsts, sems,
+                pipeline)
 
 
-def gather_unique(uniq: jnp.ndarray, vecs: jnp.ndarray,
-                  vid: jnp.ndarray, nbrs: jnp.ndarray,
-                  interpret: bool = True, pipeline_dma: bool = False,
-                  rb: int = RB, _force_dma: bool = False):
-    """Pass 2a: copy every distinct block's cold payload exactly once.
-
-    uniq [R] i32 (the whole-batch sorted-unique union, 0-padded) ->
-    (tiles [R, eps, D], vid [R, eps] i32, nbrs [R, eps, Lam] i32).
-    The double-buffered DMA schedule runs when ``pipeline_dma`` is set
-    on a compiled (non-interpret) call; ``interpret=True`` takes the
-    straight-line fallback unless ``_force_dma`` exercises the DMA
-    path under the interpreter (the emulation tests)."""
-    r = uniq.shape[0]
-    rho, eps, d = vecs.shape
-    lam = nbrs.shape[2]
+def gather_blocks(idx: jnp.ndarray, *stores: jnp.ndarray, interpret: bool,
+                  pipeline_dma: bool = True, rb: int = RB):
+    """Pass 2a: ``idx`` [R] i32 block ids (R % rb == 0) -> one
+    ``[R, ...]`` array per HBM store in ``stores`` (each [rho, ...]),
+    row i holding block ``idx[i]``. Block ids are scalar-prefetched into
+    SMEM; each block is one ``make_async_copy`` per store. Compiled,
+    a store's minor dimension must be a multiple of 128 lanes: Mosaic
+    cannot slice a block out of a narrower HBM array."""
+    r = idx.shape[0]
     assert r % rb == 0, (r, rb)
-    grid = (r // rb,)
-    use_dma = _force_dma or (pipeline_dma and not interpret)
-    kernel = (_gather_unique_dma_kernel if use_dma
-              else _gather_unique_kernel)
-    scratch = []
-    if use_dma:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((2, 1, eps, d), vecs.dtype),
-                   pltpu.VMEM((2, 1, eps), jnp.int32),
-                   pltpu.VMEM((2, 1, eps, lam), jnp.int32),
-                   pltpu.SemaphoreType.DMA((2, 3))]
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rb,), lambda i: (i,)),
-                  pl.BlockSpec((rho, eps, d), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((rho, eps), lambda i: (0, 0)),
-                  pl.BlockSpec((rho, eps, lam), lambda i: (0, 0, 0))],
-        out_specs=[pl.BlockSpec((rb, eps, d), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((rb, eps), lambda i: (i, 0)),
-                   pl.BlockSpec((rb, eps, lam), lambda i: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((r, eps, d), vecs.dtype),
-                   jax.ShapeDtypeStruct((r, eps), jnp.int32),
-                   jax.ShapeDtypeStruct((r, eps, lam), jnp.int32)],
-        scratch_shapes=scratch,
+
+    def out_spec(a):
+        zeros = (0,) * (a.ndim - 1)
+        return pl.BlockSpec((rb,) + a.shape[1:],
+                            lambda i, idx_ref: (i,) + zeros)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(r // rb,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in stores],
+        out_specs=[out_spec(a) for a in stores],
+        scratch_shapes=[pltpu.SemaphoreType.DMA((2, len(stores)))])
+    return tuple(pl.pallas_call(
+        functools.partial(_gather_kernel, rb=rb, pipeline=pipeline_dma),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((r,) + a.shape[1:], a.dtype)
+                   for a in stores],
         interpret=interpret,
-    )(uniq, vecs, vid, nbrs)
+    )(idx, *stores))
 
 
-# ----------------------------- fused pass 1+2a: in-kernel union + gather
+# ------------------------------------------------ pass 2b: rank a tile
 
-def _union_into_smem(b_ref, uniq_ref, rank_ref, slot_scr):
-    """Compute the whole-batch sorted-unique union INSIDE the kernel
-    (the sort-free ``dedup.union_slot_map`` twin of pass 1) and stage
-    the flat-slot -> unique-rank map through SMEM scratch — scalar
-    memory, where per-slot ranks that drive control/addressing belong —
-    before emitting both union outputs for pass 2b. Returns the in-
-    register ``uniq`` vector the gather below consumes."""
-    flat = b_ref[...].reshape(-1)                 # [R] target blocks
-    uniq, rank = dedup.union_slot_map(flat)
-    slot_scr[...] = rank                          # SMEM-shared slot map
-    uniq_ref[...] = uniq
-    rank_ref[...] = slot_scr[...].reshape(b_ref.shape)
-    return uniq
+def _tile_dists(q, t, metric: str):
+    """q [BQ, D] f32 vs t [BQ, E, D] -> [BQ, E] f32, the jnp fetch
+    stage's form."""
+    t32 = t.astype(jnp.float32)
+    if metric == "ip":
+        return -jnp.sum(q[:, None, :] * t32, axis=-1)
+    return jnp.sum(jnp.square(t32 - q[:, None, :]), axis=-1)
 
 
-def _gather_union_kernel(b_ref, vecs_ref, vid_ref, nbrs_ref,
-                         uniq_ref, rank_ref, tv_ref, ti_ref, tn_ref,
-                         slot_scr):
-    """Fused union + straight-line cold gather (the ``interpret=True``
-    fallback and the ``pipeline_dma=False`` path)."""
-    uniq = _union_into_smem(b_ref, uniq_ref, rank_ref, slot_scr)
-    tv_ref[...] = vecs_ref[...][uniq]
-    ti_ref[...] = vid_ref[...][uniq]
-    tn_ref[...] = nbrs_ref[...][uniq]
+def _rank_kernel(live_ref, q_ref, u_ref, t_ref, vid_ref, d_ref, ord_ref,
+                 *, metric: str, n_expand: int, eps: int):
+    live = live_ref[pl.program_id(0)] > 0
 
-
-def _gather_union_dma_kernel(b_ref, vecs_ref, vid_ref, nbrs_ref,
-                             uniq_ref, rank_ref, tv_ref, ti_ref, tn_ref,
-                             slot_scr, vscr, iscr, nscr, sems):
-    """Fused union + double-buffered cold gather: the first speculative
-    / pipelined DMA can start as soon as the in-kernel union resolves —
-    no host-visible pass-1 boundary between union and gather."""
-    uniq = _union_into_smem(b_ref, uniq_ref, rank_ref, slot_scr)
-    _double_buffered_gather(uniq, vecs_ref, vid_ref, nbrs_ref,
-                            tv_ref, ti_ref, tn_ref,
-                            vscr, iscr, nscr, sems)
-
-
-def gather_union(b: jnp.ndarray, vecs: jnp.ndarray,
-                 vid: jnp.ndarray, nbrs: jnp.ndarray,
-                 interpret: bool = True, pipeline_dma: bool = False,
-                 _force_dma: bool = False):
-    """Fused pass 1+2a: in-kernel whole-batch union, then copy every
-    distinct block's cold payload exactly once.
-
-    b [Q, F] i32 target blocks (idle slots already folded onto block
-    0) -> (uniq [R], rank2d [Q, F] i32, tiles [R, eps, D],
-    vid [R, eps] i32, nbrs [R, eps, Lam] i32) with R = Q*F — the same
-    five values the two-pass path hands pass 2b, bit-identical.
-
-    The union needs the whole-batch view, so this runs as a single
-    kernel invocation (no RB chunking); the O(R^2) union masks stay
-    comfortably in VMEM at search-round sizes (R is a few hundred).
-    The slot map is staged through an SMEM scratch buffer; DMA
-    schedule selection matches ``gather_unique``."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    qn, f = b.shape
-    r = qn * f
-    rho, eps, d = vecs.shape
-    lam = nbrs.shape[2]
-    use_dma = _force_dma or (pipeline_dma and not interpret)
-    kernel = (_gather_union_dma_kernel if use_dma
-              else _gather_union_kernel)
-    scratch = [pltpu.SMEM((r,), jnp.int32)]
-    if use_dma:
-        scratch += [pltpu.VMEM((2, 1, eps, d), vecs.dtype),
-                    pltpu.VMEM((2, 1, eps), jnp.int32),
-                    pltpu.VMEM((2, 1, eps, lam), jnp.int32),
-                    pltpu.SemaphoreType.DMA((2, 3))]
-    return pl.pallas_call(
-        kernel,
-        out_shape=[jax.ShapeDtypeStruct((r,), b.dtype),
-                   jax.ShapeDtypeStruct((qn, f), jnp.int32),
-                   jax.ShapeDtypeStruct((r, eps, d), vecs.dtype),
-                   jax.ShapeDtypeStruct((r, eps), jnp.int32),
-                   jax.ShapeDtypeStruct((r, eps, lam), jnp.int32)],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(b, vecs, vid, nbrs)
-
-
-# ------------------------------------------- pass 2b: broadcast and rank
-
-def _rank_kernel(q_ref, u_ref, rank_ref, uniq_ref, slot_ref, hotv_ref,
-                 hotid_ref, hotn_ref, tv_ref, ti_ref, tn_ref,
-                 d_ref, vout_ref, nout_ref, hit_ref, ord_ref,
-                 *, metric: str, n_expand: int):
-    u = u_ref[...]                                # [BQ, F] i32, -1 = idle
-    bq, f = u.shape
-    eps, d_dim = tv_ref.shape[1], tv_ref.shape[2]
-    lam = tn_ref.shape[2]
-
-    @pl.when((u >= 0).any())
+    @pl.when(live)
     def _live_tile():
         q = q_ref[...].astype(jnp.float32)        # [BQ, D]
-        valid = u >= 0
-        # --- tier-0 probe of the batch-unique list + hot/cold select:
-        # a hot block's tile comes from the VMEM pack (its pass-2a DMA
-        # never needed to happen), a cold one from the once-per-
-        # distinct-block copy pass 2a made
-        s = slot_ref[...][uniq_ref[...]]          # [R] hot slot (-1=cold)
-        hot_u = s >= 0
-        ss = jnp.maximum(s, 0)
-        tiles_u = jnp.where(hot_u[:, None, None], hotv_ref[...][ss],
-                            tv_ref[...])          # [R, eps, D]
-        vid_u = jnp.where(hot_u[:, None], hotid_ref[...][ss],
-                          ti_ref[...])            # [R, eps]
-        nbrs_u = jnp.where(hot_u[:, None, None], hotn_ref[...][ss],
-                           tn_ref[...])           # [R, eps, Lam]
-        # --- broadcast each distinct tile to its requesting slots
-        # through the flat-slot -> unique-rank map pass 1 carried in
-        rk = rank_ref[...].reshape(-1)            # [BQ*F] unique ranks
-        tiles = tiles_u[rk].reshape(bq, f * eps, d_dim)
-        vid = vid_u[rk].reshape(bq, f * eps)
-        nbrs = nbrs_u[rk].reshape(bq, f * eps, lam)
-        hit = hot_u[rk].reshape(bq, f)
-        # --- exact rank (same f32 form as the jnp reference)
-        t32 = tiles.astype(jnp.float32)
-        if metric == "ip":
-            dd = -jnp.einsum("qd,qed->qe", q, t32)
-        else:
-            dd = jnp.sum(jnp.square(t32 - q[:, None, :]), axis=-1)
-        # --- per-query top-M expansion order over the masked selection
-        # key (targets first, then nearest residents; same tie-breaking
-        # as the search loop: stable argsort)
-        f_valid = jnp.repeat(valid, eps, axis=1)
+        u = u_ref[...]                            # [BQ, F] i32, -1 idle
+        vid = vid_ref[...]                        # [BQ, F*eps]
+        dd = _tile_dists(q, t_ref[...], metric)   # [BQ, F*eps]
+        bq, fe = vid.shape
+        col = jax.lax.broadcasted_iota(jnp.int32, (bq, fe), 1)
+        f_valid = jnp.zeros((bq, fe), jnp.bool_)
+        is_target = jnp.zeros((bq, fe), jnp.bool_)
+        for f in range(u.shape[1]):
+            uf = u[:, f:f + 1]
+            in_f = (col >= f * eps) & (col < (f + 1) * eps)
+            f_valid = f_valid | (in_f & (uf >= 0))
+            is_target = is_target | (vid == uf)
         slot_valid = (vid >= 0) & f_valid
         dd_m = jnp.where(slot_valid, dd, jnp.inf)
-        is_target = (vid[:, :, None] == u[:, None, :]).any(-1) & (vid >= 0)
-        sel_key = jnp.where(is_target, -jnp.inf, dd_m)
-        order = jnp.argsort(sel_key, axis=1)[:, :n_expand]
+        sel_key = jnp.where(is_target & (vid >= 0), -jnp.inf, dd_m)
+        # per-query top-n_expand in stable-argsort order: the smallest
+        # untaken key, ties to the lowest column (targets first, then
+        # nearest residents, then invalid slots in column order)
+        taken = jnp.zeros((bq, fe), jnp.bool_)
+        ocol = jax.lax.broadcasted_iota(jnp.int32, (bq, n_expand), 1)
+        order = jnp.zeros((bq, n_expand), jnp.int32)
+        for m in range(n_expand):
+            kmin = jnp.min(jnp.where(taken, jnp.inf, sel_key), axis=1,
+                           keepdims=True)
+            cand = (~taken) & (sel_key == kmin)
+            pick = jnp.min(jnp.where(cand, col, fe), axis=1,
+                           keepdims=True)
+            taken = taken | (col == pick)
+            order = jnp.where(ocol == m, pick, order)
         d_ref[...] = dd
-        vout_ref[...] = vid
-        nout_ref[...] = nbrs
-        hit_ref[...] = hit.astype(jnp.int32)
-        ord_ref[...] = order.astype(jnp.int32)
+        ord_ref[...] = order
 
-    @pl.when(~(u >= 0).any())
+    @pl.when(jnp.logical_not(live))
     def _idle_tile():
         # a fully-converged tile (what compaction clusters): skip the
-        # broadcast + rank entirely, emit masked sentinels the search
-        # loop never consumes (every downstream use is gated on u >= 0)
-        d_ref[...] = jnp.zeros((bq, f * eps), jnp.float32)
-        vout_ref[...] = jnp.full((bq, f * eps), -1, jnp.int32)
-        nout_ref[...] = jnp.full((bq, f * eps, lam), -1, jnp.int32)
-        hit_ref[...] = jnp.zeros((bq, f), jnp.int32)
-        ord_ref[...] = jnp.zeros((bq, n_expand), jnp.int32)
+        # rank entirely, emit masked sentinels the search loop never
+        # consumes (every downstream use is gated on u >= 0)
+        d_ref[...] = jnp.zeros(d_ref.shape, jnp.float32)
+        ord_ref[...] = jnp.zeros(ord_ref.shape, jnp.int32)
 
+
+def rank_tiles(live, queries, u, tiles, vid, n_expand: int, *,
+               metric: str, interpret: bool, bq: int = BQ):
+    """Pass 2b: live [Q/bq] i32 tile flags, queries [Q, D], u [Q, F],
+    tiles [Q, F*eps, D], vid [Q, F*eps] -> (dists [Q, F*eps] f32,
+    order [Q, n_expand] i32)."""
+    qn, d = queries.shape
+    f = u.shape[1]
+    fe = vid.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(qn // bq,),
+        in_specs=[pl.BlockSpec((bq, d), lambda i, lv: (i, 0)),
+                  pl.BlockSpec((bq, f), lambda i, lv: (i, 0)),
+                  pl.BlockSpec((bq, fe, d), lambda i, lv: (i, 0, 0)),
+                  pl.BlockSpec((bq, fe), lambda i, lv: (i, 0))],
+        out_specs=[pl.BlockSpec((bq, fe), lambda i, lv: (i, 0)),
+                   pl.BlockSpec((bq, n_expand), lambda i, lv: (i, 0))])
+    return pl.pallas_call(
+        functools.partial(_rank_kernel, metric=metric, n_expand=n_expand,
+                          eps=fe // f),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((qn, fe), jnp.float32),
+                   jax.ShapeDtypeStruct((qn, n_expand), jnp.int32)],
+        interpret=interpret,
+    )(live, queries, u, tiles, vid)
+
+
+# ------------------------------------------------------ the whole round
 
 def fused_round(queries: jnp.ndarray, u: jnp.ndarray,
                 block_of: jnp.ndarray, hot_slot_of: jnp.ndarray,
                 hot_vecs: jnp.ndarray, hot_vid: jnp.ndarray,
                 hot_nbrs: jnp.ndarray, vecs: jnp.ndarray,
                 vid: jnp.ndarray, nbrs: jnp.ndarray, n_expand: int,
-                metric: str = "l2", interpret: bool = True,
-                bq: int = BQ, pipeline_dma: bool = False,
-                fuse_union: bool = False, _force_dma: bool = False):
-    """One search round's fetch pipeline, fused, batch-scope (see
-    module docstring).
+                metric: str = "l2", *, interpret: bool, bq: int = BQ,
+                pipeline_dma: bool = True):
+    """One search round's fetch pipeline, batch-scope (see module
+    docstring).
 
     queries [Q, D]; u [Q, F] i32 picked candidate ids (-1 = converged /
     empty slot); block_of [N]; hot_slot_of [rho]; hot pack [H, eps, ...];
@@ -352,125 +239,74 @@ def fused_round(queries: jnp.ndarray, u: jnp.ndarray,
     hit [Q, F] i32, order [Q, n_expand] i32).
 
     Dedup scope is the WHOLE batch: every distinct block across all
-    Q x F requests is gathered once and broadcast — a request in tile 3
-    rides a copy tile 0's requests triggered. ``pipeline_dma``
-    double-buffers the cold gather on compiled calls (interpret always
-    takes the straight-line fallback unless ``_force_dma``).
-    ``fuse_union`` moves the pass-1 union into the gather kernel
-    (``gather_union``: SMEM-staged slot map, no host-visible pass-1
-    intermediates) — bit-identical to the two-pass path, which stays
-    available as the conformance oracle twin."""
+    Q x F requests is gathered once and broadcast. Q % bq == 0."""
     qn, d = queries.shape
     _, f = u.shape
     assert qn % bq == 0, (qn, bq)
+    eps = vecs.shape[1]
 
     # --- pass 1: whole-batch sorted-unique union + slot -> rank map.
     # Idle slots (u = -1) fold onto block 0's rank — harmless, their
-    # outputs are masked/skipped downstream; ranks past the distinct
-    # count keep the 0 placeholder no slot maps to.
+    # outputs are masked downstream; ranks past the distinct count keep
+    # the 0 placeholder no slot maps to.
     b = block_of[jnp.maximum(u, 0)]               # [Q, F] target blocks
+    uniq, req_rank = dedup.sorted_unique_ranks(b.reshape(-1))
+    rank2d = req_rank.reshape(qn, f)
+    # --- pass 2a: copy each distinct block's cold payload once
+    r = uniq.shape[0]
+    rb = min(RB, r)
+    pad = (-r) % rb
+    uniq_p = uniq if pad == 0 else jnp.pad(uniq, (0, pad))
+    (tv,) = gather_blocks(uniq_p, vecs, interpret=interpret,
+                          pipeline_dma=pipeline_dma, rb=rb)
+    # ids and neighbor rows are narrower than a lane tile (eps and Lam <
+    # 128), which a Mosaic DMA cannot slice: XLA gathers them
+    ti, tn = vid[uniq], nbrs[uniq]
 
-    if fuse_union:
-        # fused pass 1+2a: the union resolves inside the gather kernel
-        # (sort-free twin, SMEM slot map) and the first cold DMA starts
-        # without a host-visible pass-1 boundary
-        uniq, rank2d, tv, ti, tn = gather_union(
-            b, vecs, vid, nbrs, interpret=interpret,
-            pipeline_dma=pipeline_dma, _force_dma=_force_dma)
-        r = uniq.shape[0]
-    else:
-        uniq, req_rank = dedup.sorted_unique_ranks(b.reshape(-1))
-        rank2d = req_rank.reshape(qn, f)
+    # --- tier-0 probe + hot/cold select, then broadcast each distinct
+    # block to its requesting slots through the rank map
+    s = hot_slot_of[uniq]                         # [R] hot slot, -1 cold
+    hot_u = s >= 0
+    ss = jnp.maximum(s, 0)
+    tiles_u = jnp.where(hot_u[:, None, None], hot_vecs[ss], tv[:r])
+    vid_u = jnp.where(hot_u[:, None], hot_vid[ss], ti[:r])
+    nbrs_u = jnp.where(hot_u[:, None, None], hot_nbrs[ss], tn[:r])
+    tiles = tiles_u[rank2d].reshape(qn, f * eps, d)
+    vid_q = vid_u[rank2d].reshape(qn, f * eps)
+    nbrs_q = nbrs_u[rank2d].reshape(qn, f * eps, -1)
+    hit = hot_u[rank2d]
 
-        # --- pass 2a: copy each distinct block's cold payload once
-        r = uniq.shape[0]
-        rb = min(RB, r)
-        pad = (-r) % rb
-        uniq_p = uniq if pad == 0 else jnp.pad(uniq, (0, pad))
-        tv, ti, tn = gather_unique(
-            uniq_p, vecs, vid, nbrs, interpret=interpret,
-            pipeline_dma=pipeline_dma, rb=rb, _force_dma=_force_dma)
-        tv, ti, tn = tv[:r], ti[:r], tn[:r]
-
-    # --- pass 2b: probe + hot/cold select + broadcast + rank per tile
-    n = block_of.shape[0]
-    rho, eps, _ = vecs.shape
-    h = hot_vecs.shape[0]
-    lam = nbrs.shape[2]
-    grid = (qn // bq,)
-    return pl.pallas_call(
-        functools.partial(_rank_kernel, metric=metric,
-                          n_expand=n_expand),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bq, d), lambda i: (i, 0)),
-                  pl.BlockSpec((bq, f), lambda i: (i, 0)),
-                  pl.BlockSpec((bq, f), lambda i: (i, 0)),
-                  pl.BlockSpec((r,), lambda i: (0,)),
-                  pl.BlockSpec((rho,), lambda i: (0,)),
-                  pl.BlockSpec((h, eps, d), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((h, eps), lambda i: (0, 0)),
-                  pl.BlockSpec((h, eps, lam), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((r, eps, d), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((r, eps), lambda i: (0, 0)),
-                  pl.BlockSpec((r, eps, lam), lambda i: (0, 0, 0))],
-        out_specs=[pl.BlockSpec((bq, f * eps), lambda i: (i, 0)),
-                   pl.BlockSpec((bq, f * eps), lambda i: (i, 0)),
-                   pl.BlockSpec((bq, f * eps, lam), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((bq, f), lambda i: (i, 0)),
-                   pl.BlockSpec((bq, n_expand), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((qn, f * eps), jnp.float32),
-                   jax.ShapeDtypeStruct((qn, f * eps), jnp.int32),
-                   jax.ShapeDtypeStruct((qn, f * eps, lam), jnp.int32),
-                   jax.ShapeDtypeStruct((qn, f), jnp.int32),
-                   jax.ShapeDtypeStruct((qn, n_expand), jnp.int32)],
-        interpret=interpret,
-    )(queries, u, rank2d, uniq, hot_slot_of, hot_vecs, hot_vid,
-      hot_nbrs, tv, ti, tn)
+    # --- pass 2b: exact rank + expansion order per live query tile
+    live = (u.reshape(qn // bq, bq * f) >= 0).any(axis=1)
+    dd, order = rank_tiles(live.astype(jnp.int32), queries, u, tiles,
+                           vid_q, n_expand, metric=metric,
+                           interpret=interpret, bq=bq)
+    row_live = jnp.repeat(live, bq)[:, None]
+    return (dd, jnp.where(row_live, vid_q, -1),
+            jnp.where(row_live[:, :, None], nbrs_q, -1),
+            jnp.where(row_live, hit, False).astype(jnp.int32), order)
 
 
 def tier0_fetch_rank(queries: jnp.ndarray, blocks: jnp.ndarray,
                      hot_slot_of: jnp.ndarray, hot_vecs: jnp.ndarray,
-                     cold_vecs: jnp.ndarray, metric: str = "l2",
-                     interpret: bool = True, bq: int = BQ):
+                     cold_vecs: jnp.ndarray, metric: str = "l2", *,
+                     interpret: bool):
     """queries [Q, D]; blocks [Q, F] i32; hot_slot_of [rho] i32 (-1 =
     not packed); hot_vecs [H, eps, D]; cold_vecs [rho, eps, D] ->
-    (dists [Q, F*eps] f32, hit [Q, F] i32)."""
-    qn, d = queries.shape
-    _, f = blocks.shape
-    rho, eps, _ = cold_vecs.shape
-    h = hot_vecs.shape[0]
-    assert qn % bq == 0, (qn, bq)
-    grid = (qn // bq,)
-    return pl.pallas_call(
-        functools.partial(_probe_kernel, metric=metric),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bq, d), lambda i: (i, 0)),
-                  pl.BlockSpec((bq, f), lambda i: (i, 0)),
-                  pl.BlockSpec((rho,), lambda i: (0,)),
-                  pl.BlockSpec((h, eps, d), lambda i: (0, 0, 0)),
-                  pl.BlockSpec((rho, eps, d), lambda i: (0, 0, 0))],
-        out_specs=[pl.BlockSpec((bq, f * eps), lambda i: (i, 0)),
-                   pl.BlockSpec((bq, f), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((qn, f * eps), jnp.float32),
-                   jax.ShapeDtypeStruct((qn, f), jnp.int32)],
-        interpret=interpret,
-    )(queries, blocks, hot_slot_of, hot_vecs, cold_vecs)
-
-
-def _probe_kernel(q_ref, b_ref, slot_ref, hot_ref, cold_ref,
-                  d_ref, hit_ref, *, metric: str):
-    q = q_ref[...].astype(jnp.float32)            # [BQ, D]
-    b = b_ref[...]                                # [BQ, F] i32
-    slot = slot_ref[...][b]                       # probe: [BQ, F]
+    (dists [Q, F*eps] f32, hit [Q, F] i32). The cold tiles come from
+    the block-gather kernel; the probe, the hot select and the
+    distances are jnp."""
+    qn, f = blocks.shape
+    _, eps, d = cold_vecs.shape
+    flat = blocks.reshape(-1)
+    rb = min(RB, flat.shape[0])
+    pad = (-flat.shape[0]) % rb
+    (cold,) = gather_blocks(jnp.pad(flat, (0, pad)), cold_vecs,
+                            interpret=interpret, rb=rb)
+    slot = hot_slot_of[flat]
     hit = slot >= 0
-    hot_t = hot_ref[...][jnp.maximum(slot, 0)]    # [BQ, F, eps, D]
-    cold_t = cold_ref[...][b]                     # the modeled HBM DMA
-    t = jnp.where(hit[:, :, None, None], hot_t, cold_t)
-    bq, f, eps, d_dim = t.shape
-    t = t.reshape(bq, f * eps, d_dim).astype(jnp.float32)
-    if metric == "ip":
-        d = -jnp.einsum("qd,qed->qe", q, t)
-    else:
-        d = jnp.sum(jnp.square(t - q[:, None, :]), axis=-1)
-    d_ref[...] = d
-    hit_ref[...] = hit.astype(jnp.int32)
+    t = jnp.where(hit[:, None, None], hot_vecs[jnp.maximum(slot, 0)],
+                  cold[:flat.shape[0]])
+    dd = _tile_dists(queries.astype(jnp.float32),
+                     t.reshape(qn, f * eps, d), metric)
+    return dd, hit.reshape(qn, f).astype(jnp.int32)

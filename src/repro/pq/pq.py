@@ -45,16 +45,22 @@ class PQCodebook:
         return self.centroids.nbytes
 
 
+# full f32 products: an accelerator's default matmul precision rounds
+# inputs to bf16, which flips nearest-centroid picks (|x|^2 + |c|^2 -
+# 2 x.c cancels) and leaves the codebook coarser than the f32 config says
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 @functools.partial(jax.jit, static_argnames=("iters",))
 def _lloyd(x: jnp.ndarray, init: jnp.ndarray, iters: int) -> jnp.ndarray:
     """x [N, d], init [K, d] -> [K, d]. Empty clusters keep their centroid."""
     def step(cent, _):
         d = (jnp.sum(x * x, 1, keepdims=True) + jnp.sum(cent * cent, 1)
-             - 2.0 * x @ cent.T)
+             - 2.0 * jnp.matmul(x, cent.T, precision=_HIGHEST))
         a = jnp.argmin(d, axis=1)
         one = jax.nn.one_hot(a, cent.shape[0], dtype=x.dtype)   # [N, K]
         cnt = one.sum(0)
-        tot = one.T @ x
+        tot = jnp.matmul(one.T, x, precision=_HIGHEST)
         new = jnp.where(cnt[:, None] > 0, tot / jnp.maximum(cnt[:, None], 1),
                         cent)
         return new, None
@@ -88,7 +94,7 @@ def _encode(x: jnp.ndarray, cent: jnp.ndarray) -> jnp.ndarray:
     """x [N, M, dsub], cent [M, K, dsub] -> codes [N, M] uint8."""
     d = (jnp.sum(x * x, -1)[:, :, None]
          + jnp.sum(cent * cent, -1)[None]
-         - 2.0 * jnp.einsum("nmd,mkd->nmk", x, cent))
+         - 2.0 * jnp.einsum("nmd,mkd->nmk", x, cent, precision=_HIGHEST))
     return jnp.argmin(d, axis=-1).astype(jnp.uint8)
 
 

@@ -129,11 +129,15 @@ class MeshQueryRouter:
     # ------------------------------------------------------------ stacking
     def _restack(self) -> None:
         """(Re)build the [W, ...] shard tree + per-rank offsets from the
-        current placement. Shapes never change across restacks, so the
-        compiled step executable is reused."""
+        current placement, each rank's shard placed on its own device.
+        Shapes never change across restacks, so the compiled step
+        executable is reused."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         from repro.core.device_search import stack_segments
         self._seg_stack = stack_segments(
-            [self.servers[si].segment for si in self._placement])
+            [self.servers[si].segment for si in self._placement],
+            NamedSharding(self.mesh, P("model")))
         self._offsets = np.asarray(
             [self.servers[si].offset for si in self._placement],
             np.int32)
@@ -151,14 +155,8 @@ class MeshQueryRouter:
 
     # ------------------------------------------------------------- the step
     def _build_step(self, k: int):
-        import inspect
-
         import jax
         import jax.numpy as jnp
-        try:
-            from jax import shard_map
-        except ImportError:                    # older jax releases
-            from jax.experimental.shard_map import shard_map
 
         from repro.core.device_search import (device_anns,
                                               merge_shard_topk)
@@ -216,11 +214,8 @@ class MeshQueryRouter:
                      P(None, "model"), P(None, "model"),
                      P(None, "model"), P(None, "model"),
                      P(None, "model"), P("model"))
-        flag = ("check_vma" if "check_vma"
-                in inspect.signature(shard_map).parameters
-                else "check_rep")
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **{flag: False})
+        fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(fn)
 
     def _get_step(self, k: int):
@@ -454,6 +449,9 @@ class MeshQueryRouter:
                 "dedup_cross": cx_c.sum(axis=1),
                 "spec_hits": sh_c.sum(axis=1),
                 "spec_wasted": sw_c.sum(axis=1),
+                # the mesh step searches the block graph only: no
+                # member's hot tier runs behind the router
+                "hot_tier_hits": np.zeros(io_c.shape[0], np.int64),
                 "rounds": int(rounds.max()),
                 "dma_pipelined": (self.search_params.pipeline_dma
                                   and self.search_params.fetch_impl

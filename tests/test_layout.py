@@ -90,3 +90,35 @@ def test_gp3_gain_order_variant(small_segment):
     eps = small_segment.view.layout.verts_per_block
     lay = L.make_layout(g, eps, "gp3", bnf_iters=2)
     lay.validate()
+
+
+def test_from_block_of_matches_fill_loop():
+    """Slots fill in ascending vertex id within each block — the
+    assignment loop the vectorised form replaces."""
+    rng = np.random.default_rng(5)
+    rho, eps = 40, 5
+    block_of = rng.permutation(np.repeat(np.arange(rho), eps))[:187]
+    lay = L._from_block_of(block_of.astype(np.int32), rho, eps)
+    blocks = np.full((rho, eps), -1, np.int32)
+    fill = np.zeros(rho, int)
+    for u, b in enumerate(block_of):
+        blocks[b, fill[b]] = u
+        assert lay.slot_of[u] == fill[b]
+        fill[b] += 1
+    np.testing.assert_array_equal(lay.blocks, blocks)
+
+
+def test_block_counts_order_matches_bincount():
+    """Each vertex's candidate blocks come most-neighbors first, ties to
+    the lower block id — the order of a stable argsort of -bincount."""
+    g = random_graph(150, 8, seed=2)
+    e = g.edges().astype(np.int64)
+    e = e[np.argsort(e[:, 0], kind="stable")]
+    blk = np.random.default_rng(1).integers(0, 30, e.shape[0])
+    u, b, cnt = L._block_counts(e[:, 0], blk, 30)
+    for v in range(g.num_vertices):
+        row = blk[e[:, 0] == v]
+        c = np.bincount(row, minlength=30)
+        want = np.argsort(-c, kind="stable")[: np.count_nonzero(c)]
+        np.testing.assert_array_equal(b[u == v], want)
+        np.testing.assert_array_equal(cnt[u == v], c[want])

@@ -61,3 +61,49 @@ def test_lut_batch_consistency(m, metric):
     for i in range(5):
         np.testing.assert_allclose(batch[i], adc_lut(q[i], cb),
                                    rtol=1e-5, atol=1e-5)
+
+
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every dot_general in ``jaxpr``, sub-jaxprs
+    (scan, loops, nested jits) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out.extend(_dot_precisions(inner))
+    return out
+
+
+@pytest.mark.parametrize("site", ["pq_lloyd", "pq_encode", "pairwise_jit",
+                                  "prune_rows"])
+def test_build_matmuls_run_at_full_f32_precision(site):
+    """Every matmul of the build that runs on the device asks for full
+    f32 products. An accelerator's default precision rounds f32 inputs
+    to bf16: |x|^2 + |c|^2 - 2 x.c then picks other nearest centroids
+    (or neighbours), and the chip builds a coarser segment than the CPU
+    from the same seed."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import graph as G
+    from repro.pq import pq as PQ
+    f32 = jnp.float32
+    fn, args = {
+        "pq_lloyd": (lambda x, c: PQ._lloyd(x, c, 2),
+                     (jnp.zeros((64, 8), f32), jnp.zeros((16, 8), f32))),
+        "pq_encode": (PQ._encode, (jnp.zeros((64, 4, 8), f32),
+                                   jnp.zeros((4, 16, 8), f32))),
+        "pairwise_jit": (D.pairwise_jit, (jnp.zeros((8, 16), f32),
+                                          jnp.zeros((32, 16), f32))),
+        "prune_rows": (lambda u, c: G._prune_rows(u, c, 1.0, 4, "l2"),
+                       (jnp.zeros((8, 16), f32),
+                        jnp.zeros((8, 12, 16), f32))),
+    }[site]
+    precs = _dot_precisions(jax.make_jaxpr(fn)(*args).jaxpr)
+    highest = jax.lax.Precision.HIGHEST
+    assert precs, f"{site} has no matmul to check"
+    assert all(p is not None and all(x == highest for x in p)
+               for p in precs), precs
