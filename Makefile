@@ -84,10 +84,10 @@ bench-batch:
 		--only device_speculate_sweep
 	PYTHONPATH=src $(PY) -m benchmarks.check_regression
 
-# the observability plane (repro.obs): trace/metrics/export/roundlog/
-# calibration unit + property tests, then the Perfetto-exporting trace
-# smoke and the cost-calibration harness (BENCH_* perf artifacts +
-# results/trace_smoke.json + CALIB_*.json presets land in results/)
+# the observability plane (repro.obs): trace/metrics/roundlog/
+# calibration unit + property tests, then the trace smoke and the
+# cost-calibration harness (BENCH_* perf artifacts + CALIB_*.json
+# presets land in results/)
 test-obs:
 	PYTHONPATH=src $(PY) -m pytest -x -q -m "not slow" \
 		tests/test_obs.py tests/test_trace_roundlog.py

@@ -1,13 +1,10 @@
-"""repro.obs benchmarks: the Perfetto trace smoke (a served batch
-recorded end-to-end, exported, schema-validated) and the
-measured-vs-modeled cost calibration harness (ROADMAP adaptive-plane
-v2 items 3+4).
+"""repro.obs benchmarks: the trace smoke (a served batch recorded
+end-to-end) and the measured-vs-modeled cost calibration harness
+(ROADMAP adaptive-plane v2 items 3+4).
 
 ``obs_trace_smoke`` runs a real coordinator batch under a wall-clock
-tracer + metrics registry, appends the *modeled* device-round timeline
-(the ``trace_rounds`` buffer priced through the TPU cost model), and
-writes ``results/trace_smoke.json`` — valid Chrome-trace-event JSON
-the CI obs lane re-validates and uploads.
+tracer + metrics registry and checks that the ``trace_rounds`` buffer
+folds exactly to the batch counters.
 
 ``cost_calibration`` fits ``CostModel`` constants per backend regime:
 
@@ -36,18 +33,13 @@ from repro.core.iostats import IOStats, NVME_SEGMENT, TPU_HBM_SEGMENT
 from repro.core.search import anns
 from repro.obs import (CalibrationSample, MetricsRegistry, Tracer,
                        WallClock, calibrate, fold_round_log,
-                       round_log_totals, timeline_from_round_log,
-                       validate_chrome_trace, write_chrome_trace)
-
-TRACE_PATH = os.path.join(C.ARTIFACT_DIR, "trace_smoke.json")
+                       round_log_totals)
 
 
 def obs_trace_smoke():
-    """One served batch, fully traced: coordinator spans, host-path
-    io.read spans, scheduler events, metrics registry — exported as
-    Chrome-trace-event JSON and validated in-bench. Also renders the
-    modeled device timeline from the round-granular trace buffer and
-    asserts the buffer folds exactly to the batch counters."""
+    """One served batch, fully traced: coordinator and server spans,
+    host-path io.read spans, metrics registry. Asserts the
+    round-granular trace buffer folds exactly to the batch counters."""
     import jax
     from repro.core import device_search as DS
     try:
@@ -89,16 +81,6 @@ def obs_trace_smoke():
     assert tot["hops"] == int(server.last_hops.sum())
     assert tot["tier0_hits"] == int(server.last_tier0_hits.sum())
     assert tot["dedup_saved"] == int(server.last_dedup_saved.sum())
-    # modeled device timeline rides the same trace file, its own track
-    timeline_from_round_log(records, TPU_HBM_SEGMENT, tracer=tracer,
-                            track="device-modeled")
-
-    write_chrome_trace(TRACE_PATH, tracer,
-                       metadata={"bench": "obs_trace_smoke"})
-    import json
-    with open(TRACE_PATH) as f:        # validate the round-tripped file
-        problems = validate_chrome_trace(json.load(f))
-    assert not problems, f"invalid Perfetto export: {problems}"
 
     snap = metrics.snapshot()
     C.record("obs_trace_smoke",
@@ -107,8 +89,7 @@ def obs_trace_smoke():
              device_rounds=tot["rounds"],
              metric_names=len(snap),
              serve_batches=metrics.value("serve.batches"),
-             total_block_reads=stats["total_block_reads"],
-             trace_path=os.path.basename(TRACE_PATH))
+             total_block_reads=stats["total_block_reads"])
     C.perf_artifact(
         "obs_trace_smoke", [
             {"name": "trace_events", "value": len(tracer),

@@ -78,6 +78,22 @@ Tree = dict
 _ROUND_LOG_COLS = ("live", "cold", "tier0", "joins", "joins_x",
                    "compacted", "spec_hits", "spec_wasted")
 
+# The search's named stages (DESIGN.md §6.3): each is a
+# ``jax.named_scope`` at its call sites below, so every op of the
+# compiled search carries the name in its ``op_name`` metadata and a
+# profiler trace sums device time by stage. Stages never nest, and no
+# scope sits inside a helper (``_merge_top``, ``_adc``, ``_bit_get``),
+# so a helper's work counts to the stage that calls it. The benchmark's
+# per-stage metrics read these names (PERF.md §3): renaming one, or
+# moving work between them, moves time between metrics.
+STAGES = ("search_entry",      # ADC LUT, entry points, first merge
+          "round_pick",        # compaction, top-F pick, block lookup
+          "round_fetch",       # fused_round, dedup joins, counters
+          "round_results",     # merge ranked residents into results
+          "round_expand",      # expansion targets, visited marks
+          "round_route",       # neighbour rows, PQ-ADC routing
+          "round_candidates")  # merge routed neighbours, open keys
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -594,168 +610,174 @@ def _block_search_loop(ds: DeviceSegment, queries: jnp.ndarray, lut,
             pos += 1
         t = st[-1]
 
-        # --- active mask + optional live-query compaction
-        live = jnp.isfinite(open_key).any(axis=1)            # [Q]
-        fired = jnp.asarray(False)
-        if compact:
-            frac = live.astype(jnp.float32).mean()
-            # repack only when the live rows are no longer front-packed
-            # (a dead row sits before a live one): once compacted, the
-            # carried order STAYS compacted until another query
-            # converges mid-front, so the sort + permutation gathers
-            # run only on rounds that actually change the packing —
-            # every other round takes the identity branch for free
-            unpacked = (jnp.any(jnp.logical_not(live[:-1]) & live[1:])
-                        if qn > 1 else jnp.asarray(False))
-            fired = (frac < compact_frac) & unpacked
-            # every carried array is per-query along axis 0 — the
-            # speculation trio (when on) rides the same permutation,
-            # so a staged prediction follows its query through a repack
-            carried = (cand_id, cand_key, open_key, visited, res_id,
-                       res_key, io, t0, hops, saved, saved_x) \
-                + ((spec_h, spec_w, spec_blk) if speculate else ()) \
-                + (perm, q_r, lut_r)
+        with jax.named_scope("round_pick"):
+            # --- active mask + optional live-query compaction
+            live = jnp.isfinite(open_key).any(axis=1)            # [Q]
+            fired = jnp.asarray(False)
+            if compact:
+                frac = live.astype(jnp.float32).mean()
+                # repack only when the live rows are no longer front-packed
+                # (a dead row sits before a live one): once compacted, the
+                # carried order STAYS compacted until another query
+                # converges mid-front, so the sort + permutation gathers
+                # run only on rounds that actually change the packing —
+                # every other round takes the identity branch for free
+                unpacked = (jnp.any(jnp.logical_not(live[:-1]) & live[1:])
+                            if qn > 1 else jnp.asarray(False))
+                fired = (frac < compact_frac) & unpacked
+                # every carried array is per-query along axis 0 — the
+                # speculation trio (when on) rides the same permutation,
+                # so a staged prediction follows its query through a repack
+                carried = (cand_id, cand_key, open_key, visited, res_id,
+                           res_key, io, t0, hops, saved, saved_x) \
+                    + ((spec_h, spec_w, spec_blk) if speculate else ()) \
+                    + (perm, q_r, lut_r)
 
-            def _repack(arrs):
-                # stable: live first, original order within each group;
-                # the carried q_r/lut_r rows ride the same permutation,
-                # so no later round ever re-gathers queries[perm]
-                ordr = jnp.argsort(jnp.logical_not(live))
-                return tuple(jnp.take(a, ordr, axis=0) for a in arrs)
+                def _repack(arrs):
+                    # stable: live first, original order within each group;
+                    # the carried q_r/lut_r rows ride the same permutation,
+                    # so no later round ever re-gathers queries[perm]
+                    ordr = jnp.argsort(jnp.logical_not(live))
+                    return tuple(jnp.take(a, ordr, axis=0) for a in arrs)
 
-            carried = jax.lax.cond(fired, _repack,
-                                   lambda arrs: arrs, carried)
-            (cand_id, cand_key, open_key, visited, res_id, res_key,
-             io, t0, hops, saved, saved_x) = carried[:11]
+                carried = jax.lax.cond(fired, _repack,
+                                       lambda arrs: arrs, carried)
+                (cand_id, cand_key, open_key, visited, res_id, res_key,
+                 io, t0, hops, saved, saved_x) = carried[:11]
+                if speculate:
+                    spec_h, spec_w, spec_blk = carried[11:14]
+                perm, q_r, lut_r = carried[-3:]
+            else:
+                q_r, lut_r = queries, lut
+
+            # --- pick the F best open candidates per query (converged
+            # queries pick nothing: every slot carries the -1 sentinel)
+            neg_top, picks = jax.lax.top_k(-open_key, fw)        # [Q, F]
+            f_active = jnp.isfinite(-neg_top)                    # [Q, F]
+            active = f_active[:, 0]
+            u = jnp.take_along_axis(cand_id, picks, axis=1)      # [Q, F]
+            u = jnp.where(f_active, u, -1)
+            b = ds.block_of[jnp.maximum(u, 0)]                   # [Q, F]
+
+        with jax.named_scope("round_fetch"):
+            # --- DR round stage: probe tier 0, dedup + gather the round's
+            # block union, rank, and order expansions — one fused pass
+            vid, nbrs, dd, hit, order = _round_stage(
+                ds, q_r, u, metric, fetch_impl, n_expand, tile,
+                pipeline_dma)
+            hot = hit.astype(bool) & f_active
+            cold = f_active & ~hot
+            joined, joined_x = _dedup_joins(b, cold, tile)       # [Q, F]
+            io = io + cold.sum(axis=1).astype(jnp.int32)
+            t0 = t0 + hot.sum(axis=1).astype(jnp.int32)
+            saved = saved + joined.sum(axis=1).astype(jnp.int32)
+            saved_x = saved_x + joined_x.sum(axis=1).astype(jnp.int32)
+            hops = hops + active.astype(jnp.int32)               # round trips
+
             if speculate:
-                spec_h, spec_w, spec_blk = carried[11:14]
-            perm, q_r, lut_r = carried[-3:]
-        else:
-            q_r, lut_r = queries, lut
+                # --- consume the prediction the previous round staged,
+                # against this round's authoritative requests. A PAYING
+                # cold gather (cold & ~joined — the DMAs the cost model
+                # prices) whose block was predicted is a hit: its copy was
+                # already in flight while the previous round's expansion /
+                # top-M maintenance ran, so the DMA left the critical
+                # path. A predicted block that matches NO cold request of
+                # its query is wasted bandwidth (a matched-but-joined
+                # request is neither: its gather was already someone
+                # else's). Charged at consume time, so the trace row below
+                # sums to exactly these per-query increments.
+                pred_match = (b[:, :, None]
+                              == spec_blk[:, None, :]).any(-1)   # [Q, F]
+                hit_spec = cold & ~joined & pred_match
+                used = ((spec_blk[:, :, None]
+                         == jnp.where(cold, b, -1)[:, None, :]).any(-1)
+                        & (spec_blk >= 0))                       # [Q, F]
+                sh_r = hit_spec.sum(axis=1).astype(jnp.int32)
+                sw_r = ((spec_blk >= 0) & ~used).sum(
+                    axis=1).astype(jnp.int32)
+                spec_h = spec_h + sh_r
+                spec_w = spec_w + sw_r
 
-        # --- pick the F best open candidates per query (converged
-        # queries pick nothing: every slot carries the -1 sentinel)
-        neg_top, picks = jax.lax.top_k(-open_key, fw)        # [Q, F]
-        f_active = jnp.isfinite(-neg_top)                    # [Q, F]
-        active = f_active[:, 0]
-        u = jnp.take_along_axis(cand_id, picks, axis=1)      # [Q, F]
-        u = jnp.where(f_active, u, -1)
-        b = ds.block_of[jnp.maximum(u, 0)]                   # [Q, F]
+            if trace:
+                # the round's row is the batch-level sum of exactly the
+                # masks the per-query counters just accumulated, so the
+                # log's column sums equal the counter totals identically
+                # (the fold invariant tests/test_trace_roundlog.py pins);
+                # sums are permutation-invariant, so compaction is moot
+                spec_cols = ((sh_r.sum().astype(jnp.int32),
+                              sw_r.sum().astype(jnp.int32)) if speculate
+                             else (jnp.zeros((), jnp.int32),
+                                   jnp.zeros((), jnp.int32)))
+                rlog = rlog.at[t].set(jnp.stack([
+                    active.sum().astype(jnp.int32),
+                    cold.sum().astype(jnp.int32),
+                    hot.sum().astype(jnp.int32),
+                    joined.sum().astype(jnp.int32),
+                    joined_x.sum().astype(jnp.int32),
+                    fired.astype(jnp.int32), *spec_cols]))
 
-        # --- DR round stage: probe tier 0, dedup + gather the round's
-        # block union, rank, and order expansions — one fused pass
-        vid, nbrs, dd, hit, order = _round_stage(
-            ds, q_r, u, metric, fetch_impl, n_expand, tile,
-            pipeline_dma)
-        hot = hit.astype(bool) & f_active
-        cold = f_active & ~hot
-        joined, joined_x = _dedup_joins(b, cold, tile)       # [Q, F]
-        io = io + cold.sum(axis=1).astype(jnp.int32)
-        t0 = t0 + hot.sum(axis=1).astype(jnp.int32)
-        saved = saved + joined.sum(axis=1).astype(jnp.int32)
-        saved_x = saved_x + joined_x.sum(axis=1).astype(jnp.int32)
-        hops = hops + active.astype(jnp.int32)               # round trips
+        with jax.named_scope("round_results"):
+            # --- DC: fold the exact-ranked residents into results
+            f_valid = jnp.repeat(f_active, eps, axis=1)
+            slot_valid = (vid >= 0) & f_valid
+            dd_m = jnp.where(slot_valid, dd, jnp.inf)
+            res_key, res_id = _merge_top(res_key, res_id, dd_m,
+                                         jnp.where(slot_valid, vid, -1),
+                                         res_size)
 
-        if speculate:
-            # --- consume the prediction the previous round staged,
-            # against this round's authoritative requests. A PAYING
-            # cold gather (cold & ~joined — the DMAs the cost model
-            # prices) whose block was predicted is a hit: its copy was
-            # already in flight while the previous round's expansion /
-            # top-M maintenance ran, so the DMA left the critical
-            # path. A predicted block that matches NO cold request of
-            # its query is wasted bandwidth (a matched-but-joined
-            # request is neither: its gather was already someone
-            # else's). Charged at consume time, so the trace row below
-            # sums to exactly these per-query increments.
-            pred_match = (b[:, :, None]
-                          == spec_blk[:, None, :]).any(-1)   # [Q, F]
-            hit_spec = cold & ~joined & pred_match
-            used = ((spec_blk[:, :, None]
-                     == jnp.where(cold, b, -1)[:, None, :]).any(-1)
-                    & (spec_blk >= 0))                       # [Q, F]
-            sh_r = hit_spec.sum(axis=1).astype(jnp.int32)
-            sw_r = ((spec_blk >= 0) & ~used).sum(
-                axis=1).astype(jnp.int32)
-            spec_h = spec_h + sh_r
-            spec_w = spec_w + sw_r
+        with jax.named_scope("round_expand"):
+            # --- block pruning: targets + top-((eps-1)*sigma), in the
+            # expansion order the round stage already ranked
+            is_target = (vid[:, :, None] == u[:, None, :]).any(-1) \
+                & (vid >= 0)
+            sel_key = jnp.where(is_target, -jnp.inf, dd_m)
+            ex_id = jnp.take_along_axis(vid, order, axis=1)
+            ex_valid = (jnp.take_along_axis(sel_key, order, axis=1)
+                        < jnp.inf) & active[:, None] & (ex_id >= 0)
+            ex_new = ex_valid & ~_bit_get(visited, jnp.maximum(ex_id, 0))
+            for j in range(n_expand):                        # mark expanded
+                visited = _bit_set(visited, jnp.maximum(ex_id[:, j], 0),
+                                   ex_new[:, j])
 
-        if trace:
-            # the round's row is the batch-level sum of exactly the
-            # masks the per-query counters just accumulated, so the
-            # log's column sums equal the counter totals identically
-            # (the fold invariant tests/test_trace_roundlog.py pins);
-            # sums are permutation-invariant, so compaction is moot
-            spec_cols = ((sh_r.sum().astype(jnp.int32),
-                          sw_r.sum().astype(jnp.int32)) if speculate
-                         else (jnp.zeros((), jnp.int32),
-                               jnp.zeros((), jnp.int32)))
-            rlog = rlog.at[t].set(jnp.stack([
-                active.sum().astype(jnp.int32),
-                cold.sum().astype(jnp.int32),
-                hot.sum().astype(jnp.int32),
-                joined.sum().astype(jnp.int32),
-                joined_x.sum().astype(jnp.int32),
-                fired.astype(jnp.int32), *spec_cols]))
+        with jax.named_scope("round_route"):
+            # --- collect neighbors of expanded slots, route by PQ
+            ex_nbrs = jnp.take_along_axis(
+                nbrs, order[:, :, None], axis=1)             # [Q, X, Lam]
+            flat = ex_nbrs.reshape(qn, -1)
+            f_valid = (flat >= 0) & ex_new.repeat(
+                ex_nbrs.shape[2], axis=1) & active[:, None]
+            f_safe = jnp.maximum(flat, 0)
+            f_valid &= ~_bit_get(visited, f_safe)            # skip expanded
+            f_codes = ds.pq_codes[f_safe]                        # [Q, F, M]
+            f_key = jnp.where(f_valid, _adc(lut_r, f_codes), jnp.inf)
+            f_id = jnp.where(f_valid, flat, -1)
+            if speculate:
+                # --- stage the NEXT round's prediction from the neighbors
+                # this round just PQ-routed — before they merge into the
+                # candidate pool, which is why the speculative gather can
+                # overlap the top-M maintenance below (and why it can
+                # miss: the merged pool may still prefer an older
+                # candidate). Hot-pack blocks never issue a speculative
+                # DMA (a tier-0 hit needs none), and duplicate slot
+                # predictions collapse so one block never double-counts.
+                neg_p, p_pick = jax.lax.top_k(-f_key, fw)        # [Q, F]
+                p_id = jnp.take_along_axis(f_id, p_pick, axis=1)
+                p_ok = (jnp.isfinite(-neg_p) & (p_id >= 0)
+                        & active[:, None])
+                p_blk = jnp.where(p_ok,
+                                  ds.block_of[jnp.maximum(p_id, 0)], -1)
+                p_hot = ds.hot_slot_of[jnp.maximum(p_blk, 0)] >= 0
+                p_blk = jnp.where(p_hot & (p_blk >= 0), -1, p_blk)
+                dup = ((p_blk[:, :, None] == p_blk[:, None, :])
+                       & (jnp.arange(fw)[None, :, None]
+                          > jnp.arange(fw)[None, None, :])).any(-1)
+                spec_blk = jnp.where(dup & (p_blk >= 0), -1,
+                                     p_blk).astype(jnp.int32)
 
-        # --- DC: fold the exact-ranked residents into results
-        f_valid = jnp.repeat(f_active, eps, axis=1)
-        slot_valid = (vid >= 0) & f_valid
-        dd_m = jnp.where(slot_valid, dd, jnp.inf)
-        res_key, res_id = _merge_top(res_key, res_id, dd_m,
-                                     jnp.where(slot_valid, vid, -1),
-                                     res_size)
-
-        # --- block pruning: targets + top-((eps-1)*sigma), in the
-        # expansion order the round stage already ranked
-        is_target = (vid[:, :, None] == u[:, None, :]).any(-1) \
-            & (vid >= 0)
-        sel_key = jnp.where(is_target, -jnp.inf, dd_m)
-        ex_id = jnp.take_along_axis(vid, order, axis=1)
-        ex_valid = (jnp.take_along_axis(sel_key, order, axis=1)
-                    < jnp.inf) & active[:, None] & (ex_id >= 0)
-        ex_new = ex_valid & ~_bit_get(visited, jnp.maximum(ex_id, 0))
-        for j in range(n_expand):                            # mark expanded
-            visited = _bit_set(visited, jnp.maximum(ex_id[:, j], 0),
-                               ex_new[:, j])
-
-        # --- collect neighbors of expanded slots, route by PQ
-        ex_nbrs = jnp.take_along_axis(
-            nbrs, order[:, :, None], axis=1)                 # [Q, X, Lam]
-        flat = ex_nbrs.reshape(qn, -1)
-        f_valid = (flat >= 0) & ex_new.repeat(
-            ex_nbrs.shape[2], axis=1) & active[:, None]
-        f_safe = jnp.maximum(flat, 0)
-        f_valid &= ~_bit_get(visited, f_safe)                # skip expanded
-        f_codes = ds.pq_codes[f_safe]                        # [Q, F, M]
-        f_key = jnp.where(f_valid, _adc(lut_r, f_codes), jnp.inf)
-        f_id = jnp.where(f_valid, flat, -1)
-        if speculate:
-            # --- stage the NEXT round's prediction from the neighbors
-            # this round just PQ-routed — before they merge into the
-            # candidate pool, which is why the speculative gather can
-            # overlap the top-M maintenance below (and why it can
-            # miss: the merged pool may still prefer an older
-            # candidate). Hot-pack blocks never issue a speculative
-            # DMA (a tier-0 hit needs none), and duplicate slot
-            # predictions collapse so one block never double-counts.
-            neg_p, p_pick = jax.lax.top_k(-f_key, fw)        # [Q, F]
-            p_id = jnp.take_along_axis(f_id, p_pick, axis=1)
-            p_ok = (jnp.isfinite(-neg_p) & (p_id >= 0)
-                    & active[:, None])
-            p_blk = jnp.where(p_ok,
-                              ds.block_of[jnp.maximum(p_id, 0)], -1)
-            p_hot = ds.hot_slot_of[jnp.maximum(p_blk, 0)] >= 0
-            p_blk = jnp.where(p_hot & (p_blk >= 0), -1, p_blk)
-            dup = ((p_blk[:, :, None] == p_blk[:, None, :])
-                   & (jnp.arange(fw)[None, :, None]
-                      > jnp.arange(fw)[None, None, :])).any(-1)
-            spec_blk = jnp.where(dup & (p_blk >= 0), -1,
-                                 p_blk).astype(jnp.int32)
-
-        cand_key, cand_id = _merge_top(cand_key, cand_id, f_key, f_id,
-                                       candidates)
-        open_key = _open_keys(cand_id, cand_key, visited)
+        with jax.named_scope("round_candidates"):
+            cand_key, cand_id = _merge_top(cand_key, cand_id, f_key, f_id,
+                                           candidates)
+            open_key = _open_keys(cand_id, cand_key, visited)
         out = (cand_id, cand_key, open_key, visited, res_id, res_key,
                io, t0, hops, saved, saved_x)
         if speculate:
@@ -839,32 +861,33 @@ def device_anns(ds: DeviceSegment, queries: jnp.ndarray,
     res_size = p.k + 2 * eps * fw
     queries = queries.astype(jnp.float32)
 
-    lut = _adc_lut(queries, ds.pq_cent, metric)              # [Q, M, K]
-    if seeds is not None:
-        entry = seeds.astype(jnp.int32)
-    else:
-        entry = nav_entry_points(ds, queries, beam=p.nav_beam,
-                                 hops=p.nav_hops, num=p.entry_points,
-                                 metric=metric)
-    e_codes = ds.pq_codes[jnp.maximum(entry, 0)]
-    e_key = jnp.where(entry >= 0, _adc(lut, e_codes), jnp.inf)
+    with jax.named_scope("search_entry"):
+        lut = _adc_lut(queries, ds.pq_cent, metric)              # [Q, M, K]
+        if seeds is not None:
+            entry = seeds.astype(jnp.int32)
+        else:
+            entry = nav_entry_points(ds, queries, beam=p.nav_beam,
+                                     hops=p.nav_hops, num=p.entry_points,
+                                     metric=metric)
+        e_codes = ds.pq_codes[jnp.maximum(entry, 0)]
+        e_key = jnp.where(entry >= 0, _adc(lut, e_codes), jnp.inf)
 
-    cand_id = jnp.full((qn, p.candidates), -1, jnp.int32)
-    cand_key = jnp.full((qn, p.candidates), jnp.inf)
-    cand_key, cand_id = _merge_top(cand_key, cand_id, e_key, entry,
-                                   p.candidates)
-    visited = jnp.zeros((qn, nb_words), jnp.uint32)          # expanded set
-    state = (cand_id, cand_key,
-             _open_keys(cand_id, cand_key, visited),
-             visited,
-             jnp.full((qn, res_size), -1, jnp.int32),
-             jnp.full((qn, res_size), jnp.inf),
-             jnp.zeros((qn,), jnp.int32),                    # io
-             jnp.zeros((qn,), jnp.int32),                    # tier-0 hits
-             jnp.zeros((qn,), jnp.int32),                    # hops
-             jnp.zeros((qn,), jnp.int32),                    # dedup joins
-             jnp.zeros((qn,), jnp.int32),                    # cross-tile
-             jnp.zeros((), jnp.int32))
+        cand_id = jnp.full((qn, p.candidates), -1, jnp.int32)
+        cand_key = jnp.full((qn, p.candidates), jnp.inf)
+        cand_key, cand_id = _merge_top(cand_key, cand_id, e_key, entry,
+                                       p.candidates)
+        visited = jnp.zeros((qn, nb_words), jnp.uint32)          # expanded set
+        state = (cand_id, cand_key,
+                 _open_keys(cand_id, cand_key, visited),
+                 visited,
+                 jnp.full((qn, res_size), -1, jnp.int32),
+                 jnp.full((qn, res_size), jnp.inf),
+                 jnp.zeros((qn,), jnp.int32),                    # io
+                 jnp.zeros((qn,), jnp.int32),                    # tier-0 hits
+                 jnp.zeros((qn,), jnp.int32),                    # hops
+                 jnp.zeros((qn,), jnp.int32),                    # dedup joins
+                 jnp.zeros((qn,), jnp.int32),                    # cross-tile
+                 jnp.zeros((), jnp.int32))
     state, rlog = _block_search_loop(
         ds, queries, lut, state, res_size=res_size,
         candidates=p.candidates, sigma=p.sigma, max_hops=p.max_hops,
@@ -1081,13 +1104,14 @@ def device_range_search(ds: DeviceSegment, queries: jnp.ndarray,
     nb_words = -(-n // 32)
     fw = max(p.fetch_width, 1)
     queries = queries.astype(jnp.float32)
-    lut = _adc_lut(queries, ds.pq_cent, metric)
+    with jax.named_scope("search_entry"):
+        lut = _adc_lut(queries, ds.pq_cent, metric)
 
-    entry = nav_entry_points(ds, queries, beam=p.nav_beam,
-                             hops=p.nav_hops, num=p.entry_points,
-                             metric=metric)
-    e_codes = ds.pq_codes[jnp.maximum(entry, 0)]
-    e_key = jnp.where(entry >= 0, _adc(lut, e_codes), jnp.inf)
+        entry = nav_entry_points(ds, queries, beam=p.nav_beam,
+                                 hops=p.nav_hops, num=p.entry_points,
+                                 metric=metric)
+        e_codes = ds.pq_codes[jnp.maximum(entry, 0)]
+        e_key = jnp.where(entry >= 0, _adc(lut, e_codes), jnp.inf)
 
     visited = jnp.zeros((qn, nb_words), jnp.uint32)
     res_id = jnp.zeros((qn, 0), jnp.int32)
@@ -1106,19 +1130,20 @@ def device_range_search(ds: DeviceSegment, queries: jnp.ndarray,
     for rnd in range(rounds):
         k_r = min(k_cap, c)
         res_size = k_r + 2 * eps * fw
-        cand_id = jnp.full((qn, c), -1, jnp.int32)
-        cand_key = jnp.full((qn, c), jnp.inf)
-        cand_key, cand_id = _merge_top(cand_key, cand_id, seed_key,
-                                       seed_id, c)
-        r_id = jnp.full((qn, res_size), -1, jnp.int32)
-        r_key = jnp.full((qn, res_size), jnp.inf)
-        if res_id.shape[1]:
-            r_key, r_id = _merge_top(r_key, r_id, res_key, res_id,
-                                     res_size)
-        state = (cand_id, cand_key,
-                 _open_keys(cand_id, cand_key, visited), visited,
-                 r_id, r_key, io, t0, hops, saved, saved_x,
-                 jnp.zeros((), jnp.int32))
+        with jax.named_scope("search_entry"):
+            cand_id = jnp.full((qn, c), -1, jnp.int32)
+            cand_key = jnp.full((qn, c), jnp.inf)
+            cand_key, cand_id = _merge_top(cand_key, cand_id, seed_key,
+                                           seed_id, c)
+            r_id = jnp.full((qn, res_size), -1, jnp.int32)
+            r_key = jnp.full((qn, res_size), jnp.inf)
+            if res_id.shape[1]:
+                r_key, r_id = _merge_top(r_key, r_id, res_key, res_id,
+                                         res_size)
+            state = (cand_id, cand_key,
+                     _open_keys(cand_id, cand_key, visited), visited,
+                     r_id, r_key, io, t0, hops, saved, saved_x,
+                     jnp.zeros((), jnp.int32))
         # trace stays off here: RS re-enters the loop per round, so a
         # stitched multi-round log has no single ``rounds`` to fold
         # against — the ANNS path is the traced one

@@ -131,6 +131,7 @@ def gather_blocks(idx: jnp.ndarray, *stores: jnp.ndarray, interpret: bool,
         out_shape=[jax.ShapeDtypeStruct((r,) + a.shape[1:], a.dtype)
                    for a in stores],
         interpret=interpret,
+        name="gather_blocks",
     )(idx, *stores))
 
 
@@ -217,6 +218,7 @@ def rank_tiles(live, queries, u, tiles, vid, n_expand: int, *,
         out_shape=[jax.ShapeDtypeStruct((qn, fe), jnp.float32),
                    jax.ShapeDtypeStruct((qn, n_expand), jnp.int32)],
         interpret=interpret,
+        name="rank_tiles",
     )(live, queries, u, tiles, vid)
 
 

@@ -1,21 +1,25 @@
 """repro.obs — the observability plane (DESIGN.md §6).
 
-Three layers over the search/serving stack:
+Layers over the search/serving stack:
 
   * round-granular device tracing (``roundlog`` +
     ``DeviceSearchParams.trace_rounds``) — exact per-round records of
     the batched while-loop, a lossless refinement of ``IOStats``;
-  * host span/event tracing (``trace``, injectable ``clock``) and the
-    serving ``metrics`` registry the coordinator/stores report through;
-  * ``export`` (Chrome-trace-event / Perfetto JSON) and ``calibrate``
-    (measured-vs-modeled ``CostModel`` fitting into stored presets).
+  * host span/event tracing (``trace``, injectable ``clock``), whose
+    spans also land in the JAX profiler's trace, the process's
+    jit-cache-miss and compile counter (``compiles``), and the serving
+    ``metrics`` registry the coordinator/stores report through;
+  * ``calibrate`` (measured-vs-modeled ``CostModel`` fitting into
+    stored presets).
+
+Device time per search stage comes from the profiler trace itself: the
+search's ops carry ``core.device_search.STAGES`` names.
 """
 from repro.obs.calibrate import (CalibrationPreset, CalibrationSample,
                                  calibrate, fit_cost_model,
                                  load_calibrated)
 from repro.obs.clock import ManualClock, WallClock
-from repro.obs.export import (chrome_trace, timeline_from_round_log,
-                              validate_chrome_trace, write_chrome_trace)
+from repro.obs.compiles import compile_counts
 from repro.obs.metrics import (Counter, Gauge, Histogram,
                                MetricsRegistry)
 from repro.obs.roundlog import (N_ROUND_COLS, ROUND_LOG_COLS,
@@ -26,9 +30,7 @@ from repro.obs.trace import TraceEvent, Tracer, manual_tracer
 __all__ = [
     "CalibrationPreset", "CalibrationSample", "calibrate",
     "fit_cost_model", "load_calibrated", "ManualClock", "WallClock",
-    "chrome_trace",
-    "timeline_from_round_log", "validate_chrome_trace",
-    "write_chrome_trace", "Counter", "Gauge", "Histogram",
+    "compile_counts", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "N_ROUND_COLS", "ROUND_LOG_COLS", "RoundRecord",
     "fold_round_log", "round_log_totals", "TraceEvent", "Tracer",
     "manual_tracer",

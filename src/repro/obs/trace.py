@@ -2,42 +2,46 @@
 
 A ``Tracer`` records a flat list of ``TraceEvent``s — complete spans
 (``ph="X"``: name, start, duration) and instant events (``ph="i"``) —
-against an injected clock (``repro.obs.clock``). The event vocabulary
-is deliberately tiny and maps 1:1 onto the Chrome trace-event /
-Perfetto JSON format (``repro.obs.export``), so a recorded batch can
-be dropped straight into ``ui.perfetto.dev``.
+against an injected clock (``repro.obs.clock``). Every span also enters
+a ``jax.profiler.TraceAnnotation`` of its name, so when the JAX
+profiler is on, the program's spans land on the host plane of the same
+trace as the device's ops, on the profiler's clock; with the profiler
+off an annotation records nothing.
 
 Instrumented call sites (``CachedBlockStore``, ``AsyncFetchQueue``,
-``HostSegmentServer``, ``QueryCoordinator``, ``RepackScheduler``) all
-take the tracer as an *optional* collaborator: the default is ``None``
-and every hook is behind an ``if tracer is not None`` guard, so the
-untraced hot path pays one attribute test — results and counters are
-identical with tracing on or off (asserted in tests).
+``HostSegmentServer``, ``SegmentServer``, ``QueryCoordinator``,
+``RepackScheduler``) all take the tracer as an *optional*
+collaborator: the default is ``None`` and every hook is behind an
+``if tracer is not None`` guard, so the untraced hot path pays one
+attribute test — results and counters are identical with tracing on or
+off (asserted in tests).
 
 Naming conventions (DESIGN.md §6): event names are dotted
 ``plane.what`` — ``coord.batch``, ``coord.segment``, ``host.search``,
-``io.read``, ``io.fetch_submit``, ``io.fetch_complete``,
-``sched.eval``, ``sched.repack``, ``device.round``. Categories group
-planes: ``serve`` | ``io`` | ``sched`` | ``device``.
+``server.dispatch``, ``server.wait``, ``io.read``, ``io.fetch_submit``,
+``io.fetch_complete``, ``sched.eval``, ``sched.repack``. Categories
+group planes: ``serve`` | ``io`` | ``sched``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List
+
+import jax
 
 from repro.obs.clock import ManualClock, WallClock
 
 
 @dataclasses.dataclass
 class TraceEvent:
-    """One Chrome-trace-event-shaped record (times in µs)."""
+    """One trace record (times in µs)."""
     name: str
     cat: str
     ph: str                 # "X" complete span | "i" instant
     ts_us: float            # start timestamp
     dur_us: float = 0.0     # span duration (X only)
-    track: str = "main"     # rendered as the Chrome tid (one row each)
+    track: str = "main"     # the row it belongs to (a segment, "coord")
     args: Dict = dataclasses.field(default_factory=dict)
 
 
@@ -72,27 +76,20 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "serve", track: str = "main",
              **args) -> Iterator[Dict]:
-        """Record a complete span around the ``with`` body.
+        """Record a complete span around the ``with`` body, inside a
+        profiler ``TraceAnnotation`` of the same name.
 
         Yields the args dict so the body can attach outcomes
         (``sp["tier"] = 1``) that land in the finished event."""
-        t0 = self.clock.now_us()
-        try:
-            yield args
-        finally:
-            t1 = self.clock.now_us()
-            self._push(TraceEvent(name=name, cat=cat, ph="X", ts_us=t0,
-                                  dur_us=max(t1 - t0, 0.0), track=track,
-                                  args=args))
-
-    def slice(self, name: str, ts_us: float, dur_us: float,
-              cat: str = "device", track: str = "main", **args) -> None:
-        """Record a span with *explicit* timing — used to render
-        modeled timelines (e.g. the device round log priced through a
-        ``CostModel``) where durations come from the model, not the
-        clock."""
-        self._push(TraceEvent(name=name, cat=cat, ph="X", ts_us=ts_us,
-                              dur_us=dur_us, track=track, args=args))
+        with jax.profiler.TraceAnnotation(name):
+            t0 = self.clock.now_us()
+            try:
+                yield args
+            finally:
+                t1 = self.clock.now_us()
+                self._push(TraceEvent(name=name, cat=cat, ph="X",
+                                      ts_us=t0, dur_us=max(t1 - t0, 0.0),
+                                      track=track, args=args))
 
     # ------------------------------------------------------------ access
     def __len__(self) -> int:

@@ -82,7 +82,15 @@ class SegmentServer:
     search is seeded from the exit frontier (``device_anns``'s
     ``seeds`` override), results merge by ``(dist, id)`` with
     ``tombstones`` masked from both sides, and the memory work lands
-    in the ``hot_tier_hits`` batch column."""
+    in the ``hot_tier_hits`` batch column.
+
+    ``tracer`` (optional, a ``repro.obs.trace.Tracer``; ``attach_obs``
+    wires the coordinator's) records each batch's ``server.dispatch``
+    span — the ``device_anns`` call: host enqueue, plus trace and
+    compile on a jit-cache miss, counted in its ``traces`` and
+    ``compiles`` args — and ``server.wait``, until the device has
+    finished. Without one the search makes no profiler or monitoring
+    call."""
     segment: DeviceSegment
     offset: int                   # base of this segment's id space
     num_vectors: int
@@ -92,6 +100,7 @@ class SegmentServer:
     host: Optional[object] = None  # the host Segment (repack source)
     hot_tier: Optional[object] = None   # repro.io.hottier.HotTier
     tombstones: Optional[np.ndarray] = None  # [num_vectors] bool
+    tracer: Optional[object] = None  # repro.obs.trace.Tracer (optional)
 
     def search(self, queries: np.ndarray, k: Optional[int] = None
                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -127,8 +136,12 @@ class SegmentServer:
         # (DeviceSearchParams requires candidates >= k)
         p = dataclasses.replace(
             self.params, k=k_cold, candidates=max(candidates, k_cold))
-        r = device_anns(self.segment, jnp.asarray(queries, jnp.float32),
-                        p, metric=self.metric, seeds=seeds)
+        q = jnp.asarray(queries, jnp.float32)
+        if self.tracer is not None:
+            r = self._traced_dispatch(q, p, seeds)
+        else:
+            r = device_anns(self.segment, q, p, metric=self.metric,
+                            seeds=seeds)
         self.last_io = np.asarray(r.io)
         self.last_tier0_hits = np.asarray(r.tier0_hits)
         self.last_hops = np.asarray(r.hops)
@@ -159,6 +172,23 @@ class SegmentServer:
             out_i[qi], out_d[qi] = merge_hot_cold(
                 k, route.ids[qi], route.dists[qi], ci[qi], cd[qi])
         return out_i, out_d, np.asarray(r.io)
+
+    def _traced_dispatch(self, queries, p, seeds):
+        """``device_anns`` under ``server.dispatch``, then
+        ``server.wait`` until its results are ready."""
+        import jax
+        from repro.obs.compiles import compile_counts
+        track = f"seg{self.offset}"
+        traces, compiles = compile_counts()
+        with self.tracer.span("server.dispatch", track=track) as sp:
+            r = device_anns(self.segment, queries, p, metric=self.metric,
+                            seeds=seeds)
+            traces_now, compiles_now = compile_counts()
+            sp["traces"] = traces_now - traces
+            sp["compiles"] = compiles_now - compiles
+        with self.tracer.span("server.wait", track=track):
+            jax.block_until_ready(r)
+        return r
 
     def repack(self, observed, plan=None) -> int:
         """Swap the tier-0 pack for one re-ranked by ``observed``
@@ -197,6 +227,8 @@ class SegmentServer:
         return self.host
 
     def attach_obs(self, tracer, metrics) -> None:
+        if tracer is not None and self.tracer is None:
+            self.tracer = tracer
         if self.hot_tier is not None and \
                 (tracer is not None or metrics is not None):
             self.hot_tier.attach_obs(tracer, metrics,

@@ -33,7 +33,7 @@ executable (the mesh analogue of ``repack_tier0``'s in-place pack
 swap). A settled or balanced stream plans zero moves (idempotence).
 
 Observability: ``router.route`` spans per batch, ``coord.shard`` spans
-per rank (per-rank timeline in the Perfetto export),
+per rank (a per-rank row in the profiler's trace),
 ``router.rebalance`` spans on firing evaluations, and ``(name,
 target="rank<r>")`` metrics through ``repro.obs``.
 """

@@ -5,6 +5,7 @@ traces) are marked ``slow``; the fast lane (`make test-fast` / CI's
 device lane) keeps the pure-helper tests and the kernel suite.
 """
 import dataclasses
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -220,6 +221,60 @@ def test_compaction_gathers_are_cond_gated(device_seg, small_data):
     assert on[0] == off[0], (
         f"compaction added {on[0] - off[0]} unconditional gathers per "
         f"round — the permutation must stay cond-gated")
+
+
+# ------------------------------------------------------- named stages
+
+STAGED = DeviceSearchParams(k=10, candidates=48, max_hops=256,
+                            fetch_width=2, compact_frac=0.25,
+                            speculate=True, trace_rounds=True)
+_HLO_OP = re.compile(r'^\s*(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(.*'
+                     r'op_name="([^"]*)"')
+
+
+@pytest.fixture(scope="module", params=["device_anns",
+                                        "device_range_search"])
+def staged_hlo(request, small_segment, small_data):
+    """(entry name, [(opcode, op_name path)] of every instruction) of
+    the compiled search, with every optional loop stage on."""
+    ds = DS.from_segment(small_segment, tier0_frac=0.1)
+    q = jnp.asarray(small_data[1][:8])
+    if request.param == "device_anns":
+        lowered = DS.device_anns.lower(ds, q, STAGED)
+    else:
+        lowered = DS.device_range_search.lower(ds, q, radius=1.0,
+                                               k_cap=64, p=STAGED,
+                                               rounds=2)
+    ops = [m.groups() for m in map(_HLO_OP.match,
+                                   lowered.compile().as_text().splitlines())
+           if m]
+    return request.param, ops
+
+
+@pytest.mark.slow
+def test_compiled_search_carries_every_stage_name(staged_hlo):
+    """Every stage of ``DS.STAGES`` names ops of the compiled search in
+    their ``op_name`` metadata, which the profiler's trace carries; no
+    op is under two stages."""
+    _, ops = staged_hlo
+    paths = [path.split("/") for _, path in ops]
+    assert set(DS.STAGES) <= {c for p in paths for c in p}
+    assert all(sum(c in DS.STAGES for c in p) <= 1 for p in paths)
+
+
+@pytest.mark.slow
+def test_loop_body_ops_fall_under_exactly_one_round_stage(staged_hlo):
+    """Every gather, sort, scatter and custom call of the block-search
+    loop's body is under exactly one ``round_*`` stage, so the stages'
+    device time adds up to the round's."""
+    entry, ops = staged_hlo
+    rounds = [s for s in DS.STAGES if s.startswith("round_")]
+    body = [(op, path.split("/")) for op, path in ops
+            if op in ("gather", "sort", "scatter", "custom-call")
+            and path.startswith(f"jit({entry})/while/body/")]
+    assert {"gather", "sort", "scatter"} <= {op for op, _ in body}
+    for op, path in body:
+        assert sum(c in rounds for c in path) == 1, (op, "/".join(path))
 
 
 @pytest.mark.slow

@@ -1,7 +1,7 @@
 """Unit tests for the observability plane (repro.obs, DESIGN.md §6):
-clocks, tracer, metrics registry, Chrome-trace export, perf artifacts,
-and the measured-vs-modeled cost calibration. All fast — no segment
-builds, no jax; the device round-log integration lives in
+clocks, tracer, compile counter, metrics registry, perf artifacts, and
+the measured-vs-modeled cost calibration. All fast — no segment
+builds; the device round-log integration lives in
 tests/test_trace_roundlog.py."""
 import dataclasses
 import json
@@ -13,10 +13,8 @@ from repro.core.iostats import IOStats, NVME_SEGMENT, TPU_HBM_SEGMENT
 from repro.obs import (CalibrationPreset, CalibrationSample, Counter,
                        Gauge, Histogram, ManualClock, MetricsRegistry,
                        RoundRecord, Tracer, WallClock, calibrate,
-                       chrome_trace, fit_cost_model, fold_round_log,
-                       manual_tracer, round_log_totals,
-                       timeline_from_round_log, validate_chrome_trace,
-                       write_chrome_trace)
+                       compile_counts, fit_cost_model, fold_round_log,
+                       manual_tracer, round_log_totals)
 
 
 # ------------------------------------------------------------------ clocks
@@ -69,10 +67,59 @@ def test_tracer_span_records_on_exception():
 def test_tracer_event_and_slice():
     tr = manual_tracer(auto_tick_us=1.0)
     tr.event("sched.repack", cat="sched", target="seg0")
-    tr.slice("device.round", ts_us=100.0, dur_us=5.0, live=8)
-    inst, sl = tr.events
+    inst, = tr.events
     assert inst.ph == "i" and inst.args == {"target": "seg0"}
-    assert sl.ph == "X" and sl.ts_us == 100.0 and sl.dur_us == 5.0
+    assert inst.ts_us == 0.0 and inst.cat == "sched"
+
+
+def test_tracer_span_enters_profiler_annotation(monkeypatch):
+    """Every span is also a ``jax.profiler.TraceAnnotation`` of its
+    name, entered around the span's body, so the program's spans reach
+    the profiler's trace; instant events are not annotated."""
+    import jax
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = manual_tracer()
+    with tr.span("coord.batch"):
+        with tr.span("server.dispatch"):
+            tr.event("io.read")
+        seen.append(("body", None))
+    assert seen == [("enter", "coord.batch"), ("enter", "server.dispatch"),
+                    ("exit", "server.dispatch"), ("body", None),
+                    ("exit", "coord.batch")]
+    assert [e.name for e in tr.events] == ["io.read", "server.dispatch",
+                                           "coord.batch"]
+
+
+def test_compile_counts_count_outermost_traces_and_compiles():
+    """A jit-cache miss counts one trace however many jitted functions
+    it traces inside, and one compile; a warm call counts neither."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x):
+        return jnp.take_along_axis(x, jnp.argsort(x, axis=1), axis=1)
+
+    compile_counts()                       # registers the listeners
+    t0, c0 = compile_counts()
+    f(np.ones((3, 5), np.float32)).block_until_ready()
+    t1, c1 = compile_counts()
+    f(np.zeros((3, 5), np.float32)).block_until_ready()
+    t2, c2 = compile_counts()
+    assert (t1 - t0, c1 - c0) == (1, 1)
+    assert (t2, c2) == (t1, c1)
 
 
 def test_tracer_head_capture_drops_past_max_events():
@@ -142,107 +189,6 @@ def test_registry_kind_mismatch_raises():
         m.histogram("serve.batches")
     # same name under a DIFFERENT target is a separate instrument
     assert isinstance(m.gauge("serve.batches", "segX"), Gauge)
-
-
-# ------------------------------------------------------------------ export
-def _demo_tracer():
-    tr = Tracer(clock=ManualClock(auto_tick_us=1.0))
-    with tr.span("coord.batch", track="coord", n_queries=8):
-        tr.event("io.read", cat="io", track="io", block=3)
-    return tr
-
-
-def test_chrome_trace_structure():
-    tr = _demo_tracer()
-    obj = chrome_trace(tr, metadata={"run": "t"})
-    assert validate_chrome_trace(obj) == []
-    evs = obj["traceEvents"]
-    metas = [e for e in evs if e["ph"] == "M"]
-    assert {m["args"]["name"] for m in metas} == {"coord", "io"}
-    tids = {m["args"]["name"]: m["tid"] for m in metas}
-    x = next(e for e in evs if e["ph"] == "X")
-    i = next(e for e in evs if e["ph"] == "i")
-    assert x["tid"] == tids["coord"] and i["tid"] == tids["io"]
-    assert x["dur"] >= 0 and i["s"] == "t"
-    assert obj["metadata"] == {"run": "t"}
-
-
-def test_chrome_trace_reports_dropped():
-    tr = Tracer(clock=ManualClock(auto_tick_us=1.0), max_events=1)
-    tr.event("a")
-    tr.event("b")
-    assert chrome_trace(tr)["obs_dropped_events"] == 1
-
-
-def test_write_chrome_trace_round_trip(tmp_path):
-    path = tmp_path / "deep" / "trace.json"   # parent dir is created
-    write_chrome_trace(path, _demo_tracer())
-    with open(path) as f:
-        assert validate_chrome_trace(json.load(f)) == []
-
-
-def test_validate_chrome_trace_catches_corruption():
-    assert validate_chrome_trace([]) != []
-    assert validate_chrome_trace({"traceEvents": 3}) != []
-    bad = {"traceEvents": [
-        {"ph": "Q", "name": "x", "pid": 1, "tid": 1},          # bad ph
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 1},    # no name
-        {"ph": "X", "name": "x", "pid": 1, "tid": 1, "ts": 0,
-         "dur": -1},                                           # bad dur
-        {"ph": "i", "name": "x", "pid": 1, "tid": 1, "ts": "a"}]}
-    assert len(validate_chrome_trace(bad)) == 4
-
-
-def test_timeline_from_round_log_modeled_durations():
-    records = [RoundRecord(0, live=8, cold=10, tier0=2, joins=3,
-                           joins_x=1, compacted=False),
-               RoundRecord(1, live=4, cold=6, tier0=1, joins=1,
-                           joins_x=0, compacted=True)]
-    cm = TPU_HBM_SEGMENT
-    tr = timeline_from_round_log(records, cm)
-    a, b = tr.by_name("device.round")
-    t_stream = cm.t_batch_block if cm.t_batch_block else cm.t_block_io
-    want0 = (cm.t_round + 8 * cm.t_round_comp + 7 * t_stream
-             + 2 * cm.t_tier0_hit + 3 * cm.t_dedup_hit)
-    assert a.ts_us == 0.0 and a.dur_us == pytest.approx(want0)
-    assert b.ts_us == pytest.approx(a.dur_us)   # back-to-back slices
-    assert a.args["live"] == 8 and b.args["compacted"] is True
-    assert validate_chrome_trace(chrome_trace(tr)) == []
-
-
-def test_timeline_dma_track_renders_speculative_overlap():
-    """``dma_track=True`` puts the gather stream on its own row: a
-    round's demand DMAs overlap its own round slice, while its
-    speculatively pre-issued blocks render back in the PREVIOUS round
-    (where the copies were actually in flight). The round slices stay
-    bit-compatible with the default rendering."""
-    records = [RoundRecord(0, live=8, cold=10, tier0=2, joins=3,
-                           joins_x=1, compacted=False),
-               RoundRecord(1, live=4, cold=6, tier0=1, joins=1,
-                           joins_x=0, compacted=True, spec_hits=2,
-                           spec_wasted=1)]
-    cm = TPU_HBM_SEGMENT
-    base = timeline_from_round_log(records, cm)
-    tr = timeline_from_round_log(records, cm, dma_track=True)
-    for a, b in zip(base.by_name("device.round"),
-                    tr.by_name("device.round")):
-        assert a.ts_us == b.ts_us and a.dur_us == b.dur_us
-        assert a.args["spec_hits"] == b.args["spec_hits"]
-    t_stream = cm.t_batch_block if cm.t_batch_block else cm.t_block_io
-    r0, r1 = tr.by_name("device.round")
-    d0, d1 = tr.by_name("device.dma")
-    # demand streams start WITH their round (overlapping its compute)
-    assert d0.ts_us == r0.ts_us and d0.args["blocks"] == 10 - 3
-    assert d1.ts_us == pytest.approx(r1.ts_us)
-    assert d1.args["blocks"] == 6 - 1 - 2      # spec hits left the demand
-    assert d1.dur_us == pytest.approx(3 * t_stream)
-    # round 1's speculative copies render back in round 0
-    spec, = tr.by_name("device.dma.spec")
-    assert spec.ts_us == r0.ts_us
-    assert spec.dur_us == pytest.approx((2 + 1) * t_stream)
-    assert spec.args["spec_hits"] == 2 and spec.args["spec_wasted"] == 1
-    assert {d0.track, d1.track, spec.track} == {"device.dma"}
-    assert validate_chrome_trace(chrome_trace(tr)) == []
 
 
 # ---------------------------------------------------------- round-log fold

@@ -114,6 +114,60 @@ def test_server_k_above_beam_widens(two_segments):
 
 
 @pytest.mark.slow
+def test_server_spans_count_traces_and_compiles(two_segments,
+                                                monkeypatch):
+    """A traced server records ``server.dispatch`` and ``server.wait``
+    per batch; the dispatch counts one trace and one compile on a new
+    batch shape and none on the next call of that shape. Untraced, the
+    server makes no profiler or monitoring call and returns the same
+    answers and counters."""
+    import jax
+
+    from repro.obs import compiles, manual_tracer
+
+    xs, servers = two_segments
+    q = query_set(xs[0], 6, seed=5)          # a batch shape of its own
+    tr = manual_tracer()
+    traced = dataclasses.replace(servers[0], tracer=tr)
+    traced.search(q, k=10)
+    want = traced.search(q, k=10)
+    assert [e.name for e in tr.events] == ["server.dispatch",
+                                           "server.wait"] * 2
+    first, _, again, _ = tr.events
+    assert (first.args["traces"], first.args["compiles"]) == (1, 1)
+    assert (again.args["traces"], again.args["compiles"]) == (0, 0)
+    assert first.track == "seg0"
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("untraced search called the profiler")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(compiles, "compile_counts", forbidden)
+    plain = dataclasses.replace(servers[0])
+    assert plain.tracer is None
+    got = plain.search(q, k=10)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    for key, value in traced.batch_stats().items():
+        np.testing.assert_array_equal(plain.batch_stats()[key], value)
+
+
+@pytest.mark.slow
+def test_coordinator_attaches_its_tracer_to_servers(two_segments):
+    from repro.obs import manual_tracer
+
+    xs, servers = two_segments
+    tr = manual_tracer()
+    fresh = [dataclasses.replace(s) for s in servers]
+    coord = QueryCoordinator(fresh, tracer=tr)
+    coord.search(query_set(xs[0], 4, seed=4), k=5)
+    assert all(s.tracer is tr for s in fresh)
+    assert [e.name for e in tr.events].count("server.dispatch") == 2
+    assert {e.track for e in tr.by_name("server.wait")} == {
+        f"seg{s.offset}" for s in fresh}
+
+
+@pytest.mark.slow
 def test_coordinator_pruning_hook(two_segments):
     xs, servers = two_segments
     q = query_set(xs[0], 4, seed=4)
