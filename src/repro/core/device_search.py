@@ -351,36 +351,33 @@ def _merge_top(keys, ids, new_keys, new_ids, size: int, extra=None,
 
     keys/ids [Q, A], new_* [Q, B] -> [Q, size]. Invalid slots: id < 0,
     key = +inf. ``extra`` (optional int32 payload, e.g. visited flags)
-    rides along."""
+    rides along.
+
+    Both orderings are stable multi-operand sorts that carry the ids,
+    keys and payload themselves, so no permutation is gathered after
+    them (a gather by a sorted order costs several times the sort on
+    the TPU)."""
     k = jnp.concatenate([keys, new_keys], axis=1)
     i = jnp.concatenate([ids, new_ids], axis=1)
-    e = (jnp.concatenate([extra, new_extra], axis=1)
-         if extra is not None else None)
-    # dedupe: sort by (id asc); duplicates adjacent; keep the first
-    # occurrence with the *smallest key* -> sort by (id, key)
-    order = jnp.lexsort((k, i))
-    k = jnp.take_along_axis(k, order, axis=1)
-    i = jnp.take_along_axis(i, order, axis=1)
-    if e is not None:
-        # keep the max extra among duplicates (visited wins): approximate
-        # by taking the flag of the kept (first) occurrence after lexsort
-        # with visited as secondary key desc would be ideal; visited
-        # entries also carry +inf keys in our usage, so (id, key) order
-        # already puts the live entry first.
-        e = jnp.take_along_axis(e, order, axis=1)
+    ops = (i, k)
+    if extra is not None:
+        ops += (jnp.concatenate([extra, new_extra], axis=1),)
+    # dedupe: sort by (id, key), stable; duplicates are adjacent and the
+    # first of a run has the smallest key, the earlier slot on a tie
+    i, k, *e = jax.lax.sort(ops, dimension=1, num_keys=2, is_stable=True)
     dup = jnp.concatenate(
         [jnp.zeros((i.shape[0], 1), bool), i[:, 1:] == i[:, :-1]], axis=1)
     dup |= i < 0
     k = jnp.where(dup, jnp.inf, k)
     i = jnp.where(dup, -1, i)
-    order2 = jnp.argsort(k, axis=1)[:, :size]
-    k = jnp.take_along_axis(k, order2, axis=1)
-    i = jnp.take_along_axis(i, order2, axis=1)
-    if e is not None:
-        e = jnp.where(dup, 0, e)
-        e = jnp.take_along_axis(e, order2, axis=1)
-        return k, i, e
-    return k, i
+    # ``extra`` keeps the flag of the occurrence kept above: the slot
+    # of the smallest key, and on an exact tie the existing list's
+    # slot (it comes first), so a re-offered id keeps its flag; the
+    # dropped duplicates' flags are cleared.
+    e = [jnp.where(dup, 0, x) for x in e]
+    # top: stable sort by key, keep the first ``size``
+    out = jax.lax.sort((k, i, *e), dimension=1, num_keys=1, is_stable=True)
+    return tuple(x[:, :size] for x in out)
 
 
 def _bit_get(mask: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:

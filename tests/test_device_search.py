@@ -7,6 +7,7 @@ device lane) keeps the pure-helper tests and the kernel suite.
 import dataclasses
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -566,6 +567,141 @@ def test_merge_top_dedup():
     assert 11 in np.asarray(i[0]).tolist()
     vals = np.asarray(i[0]).tolist()
     assert len([v for v in vals if v == 9]) == 1
+
+
+def _merge_top_gather(keys, ids, new_keys, new_ids, size, extra=None,
+                      new_extra=None):
+    """The merge as an index sort followed by gathers: a lexsort by
+    (id, key), then a stable argsort by key, each applied to the ids,
+    keys and payload with ``take_along_axis``. ``_merge_top`` must give
+    the same bits."""
+    k = jnp.concatenate([keys, new_keys], axis=1)
+    i = jnp.concatenate([ids, new_ids], axis=1)
+    order = jnp.lexsort((k, i))
+    k = jnp.take_along_axis(k, order, axis=1)
+    i = jnp.take_along_axis(i, order, axis=1)
+    dup = jnp.concatenate(
+        [jnp.zeros((i.shape[0], 1), bool), i[:, 1:] == i[:, :-1]], axis=1)
+    dup |= i < 0
+    k = jnp.where(dup, jnp.inf, k)
+    i = jnp.where(dup, -1, i)
+    order2 = jnp.argsort(k, axis=1)[:, :size]
+    out = (jnp.take_along_axis(k, order2, axis=1),
+           jnp.take_along_axis(i, order2, axis=1))
+    if extra is None:
+        return out
+    e = jnp.concatenate([extra, new_extra], axis=1)
+    e = jnp.where(dup, 0, jnp.take_along_axis(e, order, axis=1))
+    return out + (jnp.take_along_axis(e, order2, axis=1),)
+
+
+def _merge_top_np(keys, ids, new_keys, new_ids, size, extra=None,
+                  new_extra=None):
+    """NumPy reference of the same formulation."""
+    k = np.concatenate([keys, new_keys], axis=1)
+    i = np.concatenate([ids, new_ids], axis=1)
+    order = np.lexsort((k, i), axis=1)
+    k = np.take_along_axis(k, order, axis=1)
+    i = np.take_along_axis(i, order, axis=1)
+    dup = np.zeros(i.shape, bool)
+    dup[:, 1:] = i[:, 1:] == i[:, :-1]
+    dup |= i < 0
+    k = np.where(dup, np.inf, k).astype(np.float32)
+    i = np.where(dup, -1, i).astype(np.int32)
+    order2 = np.argsort(k, axis=1, kind="stable")[:, :size]
+    out = (np.take_along_axis(k, order2, axis=1),
+           np.take_along_axis(i, order2, axis=1))
+    if extra is None:
+        return out
+    e = np.concatenate([extra, new_extra], axis=1)
+    e = np.where(dup, 0, np.take_along_axis(e, order, axis=1))
+    return out + (np.take_along_axis(e, order2, axis=1).astype(np.int32),)
+
+
+def _merge_inputs(rng, q, a, b, id_range, key_levels, invalid):
+    """Random merge operands: keys drawn from ``key_levels`` values (few
+    levels = heavy ties), ids from [0, id_range) (small range = many
+    duplicates, often with different keys), a share ``invalid`` of
+    slots id -1 / key inf, and a few live ids with an inf key."""
+    def side(w):
+        key = rng.integers(0, key_levels, (q, w)).astype(np.float32) / 4
+        ids = rng.integers(0, id_range, (q, w)).astype(np.int32)
+        bad = rng.random((q, w)) < invalid
+        ids[bad] = -1
+        key[bad | (rng.random((q, w)) < 0.05)] = np.inf
+        flag = rng.integers(0, 2, (q, w)).astype(np.int32)
+        return key, ids, flag
+    (k0, i0, e0), (k1, i1, e1) = side(a), side(b)
+    return k0, i0, k1, i1, e0, e1
+
+
+@pytest.mark.parametrize("with_extra", [False, True])
+@pytest.mark.parametrize("case", [
+    # name: (Q, A, B, size, id_range, key_levels, invalid share)
+    ("key_ties", (8, 40, 12, 24, 10_000, 3, 0.1)),
+    ("dup_ids", (8, 40, 12, 24, 16, 1_000, 0.1)),
+    ("invalid_slots", (8, 40, 12, 24, 64, 20, 0.6)),
+    ("size_is_width", (8, 40, 12, 52, 32, 5, 0.2)),
+    ("candidate_shape", (4, 1024, 186, 1024, 1500, 50, 0.3)),
+], ids=lambda c: c[0] if isinstance(c, tuple) else None)
+def test_merge_top_bit_identical_to_gather_formulation(case, with_extra):
+    """``_merge_top`` (sorts that carry their payloads) returns the same
+    bits as a lexsort + stable argsort + ``take_along_axis`` merge."""
+    _, (q, a, b, size, id_range, key_levels, invalid) = case
+    rng = np.random.default_rng(a * 31 + b + size + id_range)
+    for _ in range(5):
+        k0, i0, k1, i1, e0, e1 = _merge_inputs(
+            rng, q, a, b, id_range, key_levels, invalid)
+        extra = (e0, e1) if with_extra else (None, None)
+        want = _merge_top_np(k0, i0, k1, i1, size, *extra)
+        got = DS._merge_top(*map(jnp.asarray, (k0, i0, k1, i1)), size,
+                            *(None if x is None else jnp.asarray(x)
+                              for x in extra))
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            assert g.shape == (q, size) and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), w)
+
+
+@pytest.mark.parametrize("shape", [
+    # (Q, A, B, size, extra): the cell's candidate and result merges,
+    # and the navigation beam's merge with its visited payload
+    (256, 1024, 186, 1024, False),
+    (256, 34, 12, 34, False),
+    (256, 8, 8, 8, True),
+])
+def test_merge_top_lowers_without_gather(shape):
+    """The merge is two payload-carrying sorts: its StableHLO has sorts
+    and no gather, where the index-sort formulation has gathers."""
+    q, a, b, size, with_extra = shape
+    f32 = lambda w: jax.ShapeDtypeStruct((q, w), jnp.float32)
+    i32 = lambda w: jax.ShapeDtypeStruct((q, w), jnp.int32)
+    args = (f32(a), i32(a), f32(b), i32(b), size)
+    args += (i32(a), i32(b)) if with_extra else ()
+    new = jax.jit(DS._merge_top, static_argnums=4).lower(*args).as_text()
+    old = jax.jit(_merge_top_gather, static_argnums=4).lower(*args).as_text()
+    assert "gather" not in new
+    assert new.count("stablehlo.sort") == 2
+    assert "gather" in old
+
+
+@pytest.mark.slow
+def test_device_anns_identical_with_gather_merge(device_seg, small_data,
+                                                 monkeypatch):
+    """The whole search gives the same ids, dists and counters with the
+    payload-carrying merge as with the index-sort + gather merge."""
+    _, q = small_data
+    p = dataclasses.replace(P48, max_hops=64)
+    search = lambda: jax.jit(DS.device_anns.__wrapped__,
+                             static_argnames=("p", "metric"))(
+        device_seg, jnp.asarray(q), p)
+    new = search()
+    monkeypatch.setattr(DS, "_merge_top", _merge_top_gather)
+    old = search()
+    for field in ("ids", "dists", "io", "hops", "tier0_hits", "rounds"):
+        np.testing.assert_array_equal(np.asarray(getattr(new, field)),
+                                      np.asarray(getattr(old, field)),
+                                      err_msg=field)
 
 
 def test_device_search_params_validation():
