@@ -3,6 +3,11 @@ metric readers by name, builds the served index from the configuration, drives
 the measured window through the program's serving path, checks what the
 window served against the plain reference, and returns the result line.
 
+A configuration's ``segments`` (1 when absent) cuts its data into that
+many equal contiguous segments, one per chip of the cell, served by one
+``MeshQueryRouter`` under the ``QueryCoordinator``; one segment is
+served by its ``SegmentServer`` alone.
+
 Everything that belongs to one configuration, traffic mix or metric is
 data or a reader of its own, found by the name ``BENCHMARK.json`` gives:
 
@@ -33,6 +38,7 @@ if BENCH not in sys.path:
 
 import data as bench_data  # noqa: E402
 import reference  # noqa: E402
+import scope_reduce  # noqa: E402
 import trace_reduce  # noqa: E402
 
 
@@ -88,8 +94,8 @@ class Batch:
     t1: float
     rows: int
     valid: int
-    device_rounds: List[int]    # rounds each device ran for this batch
-    paying_dmas: int            # io - dedup_saved, summed over segments
+    device_rounds: List[int]    # rounds each rank ran, in mesh order
+    paying_dmas: int            # io - dedup_saved, summed over ranks
 
 
 @dataclasses.dataclass
@@ -105,6 +111,9 @@ class Run:
     checks: Dict[str, dict] = dataclasses.field(default_factory=dict)
     spans: list = dataclasses.field(default_factory=list)  # obs.Tracer
     trace: Optional[trace_reduce.TraceSummary] = None
+    # device plane name -> op event name -> the op's ``op_name`` path
+    op_paths: Dict[str, Dict[str, str]] = dataclasses.field(
+        default_factory=dict)
     peaks: object = None
 
     @property
@@ -133,32 +142,67 @@ def segment_params(cfg: dict):
                          metric=s["metric"])
 
 
-def build_server(cfg: dict, x: np.ndarray, run: Run):
-    """Build and pack the segment of ``x`` and wrap it in a
-    ``SegmentServer``."""
+def segments(cfg: dict, cell: dict) -> int:
+    """The configuration's ``segments`` (1 when absent), checked against
+    the cell: several segments are served one per chip, so the cell
+    asks for as many chips, and they share ``n`` equally."""
+    count = int(cfg.get("segments", 1))
+    if count < 1:
+        raise ValueError(f"segments must be >= 1, not {count}")
+    if count > 1 and cell["chips"] != count:
+        raise ValueError(
+            f"{count} segments are served one per chip, but cell "
+            f"{cell['name']!r} asks for {cell['chips']} chips")
+    if cfg["n"] % count:
+        raise ValueError(f"n = {cfg['n']} does not split into {count} "
+                         f"equal segments")
+    return count
+
+
+def build_target(cfg: dict, x: np.ndarray, devices, run: Run):
+    """Build and pack the served index of ``x`` as the configuration's
+    ``segments`` contiguous segments, segment ``s`` on ``devices[s]``
+    (rows ``[s*n/S, (s+1)*n/S)``, ids offset by ``s*n/S``), each wrapped
+    in a ``SegmentServer``. One segment is served by its server; several
+    by a ``MeshQueryRouter`` over a 1 x S mesh of ``devices``. Returns
+    (the target, its servers)."""
     import jax
+    from jax.sharding import Mesh
 
     from repro.core.device_search import from_segment
     from repro.core.graph import build_graph
     from repro.core.params import DeviceSearchParams
     from repro.core.segment import build_segment
     from repro.serving.coordinator import SegmentServer
+    from repro.serving.router import MeshQueryRouter
 
     params = segment_params(cfg)
-    with _span("bench.build_graph"):
-        t = time.perf_counter()
-        graph = build_graph(x, params.graph, params.metric)
-        run.build_graph_s = time.perf_counter() - t
-    with _span("bench.build_segment"):
-        seg = build_segment(x, params, graph=graph)
-        ds = from_segment(seg)
-        jax.block_until_ready(ds)
+    serve = DeviceSearchParams(**cfg["serve"])
+    count = len(devices)
+    rows = x.shape[0] // count
+    servers = []
+    for s, device in enumerate(devices):
+        part = x[s * rows:(s + 1) * rows]
+        with jax.default_device(device):
+            with _span("bench.build_graph"):
+                t = time.perf_counter()
+                graph = build_graph(part, params.graph, params.metric)
+                run.build_graph_s += time.perf_counter() - t
+            with _span("bench.build_segment"):
+                seg = build_segment(part, params, graph=graph)
+                ds = from_segment(seg)
+                jax.block_until_ready(ds)
+        servers.append(SegmentServer(segment=ds, offset=s * rows,
+                                     num_vectors=rows, params=serve,
+                                     metric=params.metric))
+    ds = servers[0].segment
     run.shapes = {"eps": int(ds.vid.shape[1]),
                   "dim": int(ds.vecs.shape[2]),
                   "itemsize": int(ds.vecs.dtype.itemsize)}
-    return SegmentServer(segment=ds, offset=0, num_vectors=x.shape[0],
-                         params=DeviceSearchParams(**cfg["serve"]),
-                         metric=params.metric)
+    if count == 1:
+        return servers[0], servers
+    mesh = Mesh(np.asarray(devices).reshape(1, count), ("data", "model"))
+    return MeshQueryRouter(servers, mesh=mesh), servers
 
 
 def _span(name: str):
@@ -166,16 +210,25 @@ def _span(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def index_bytes(server) -> int:
-    """Bytes of the served device index arrays."""
+def index_bytes(servers) -> int:
+    """Bytes of the served device index arrays, each served segment
+    counted once."""
     import jax
-    return int(sum(a.nbytes for a in jax.tree.leaves(server.segment)))
+    return int(sum(a.nbytes for s in servers
+                   for a in jax.tree.leaves(s.segment)))
 
 
-def batch_counts(server):
-    """(rounds per device, paying DMAs) of the batch just served."""
-    paying = int(server.last_io.sum()) - int(server.last_dedup_saved.sum())
-    return [int(server.last_rounds)], paying
+def batch_counts(target):
+    """(rounds per rank in mesh order, paying DMAs summed over ranks) of
+    the batch just served, through the ``SegmentTarget`` protocol."""
+    from repro.serving.target import batch_stats
+    stats = batch_stats(target)
+    paying = int(stats["io"].sum()) - int(stats["dedup_saved"].sum())
+    per_rank = getattr(target, "last_per_rank", None)
+    if per_rank:
+        return [int(per_rank[r].batch_rounds)
+                for r in range(len(per_rank))], paying
+    return [int(stats["rounds"])], paying
 
 
 # -------------------------------------------------------------- traffic
@@ -197,14 +250,14 @@ class Served:
 def _serve_batch(ctx, batcher, qidx_of):
     """Send the batcher's next batch through the coordinator, record its
     counts, and return (query indices, ids, dists)."""
-    coord, run, server, k = ctx
+    coord, run, target, k = ctx
     with _span("bench.next_batch"):
         qb, rids, valid = batcher.next_batch()
     t0 = time.perf_counter()
     with _span("bench.search"):
         gi, gd, _ = coord.search(qb, k)
     t1 = time.perf_counter()
-    rounds, paying = batch_counts(server)
+    rounds, paying = batch_counts(target)
     run.batches.append(Batch(t0, t1, qb.shape[0], valid, rounds, paying))
     qidx = np.asarray([qidx_of.pop(r) for r in rids], np.int64)
     return qidx, gi[:valid], gd[:valid]
@@ -254,9 +307,8 @@ def closed_loop(ctx, pool, traffic, seconds):
 
 # --------------------------------------------------------------- a run
 
-def _memory_peak(devices) -> int:
-    return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                   for d in devices))
+def _memory_peak(device) -> int:
+    return int((device.memory_stats() or {}).get("peak_bytes_in_use", 0))
 
 
 def _find_xplane(d: str) -> str:
@@ -296,16 +348,17 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
 
     spec = load_spec(root)
     cell, cfg, traffic = find_cell(spec, root, cell_name)
+    count = segments(cfg, cell)
     run = Run(config=cfg, peaks=peaks)
     devices = jax.devices()[:cell["chips"]]
 
     with _span("bench.data"):
         x, pool = bench_data.cell_inputs(cfg, traffic, seed)
-    server = build_server(cfg, x, run)
+    target, servers = build_target(cfg, x, devices[:count], run)
     tracer = Tracer(clock=WallClock()) if trace else None
-    coord = QueryCoordinator([server], tracer=tracer)
-    run.index_hbm_bytes = index_bytes(server)
-    ctx = (coord, run, server, traffic["k"])
+    coord = QueryCoordinator([target], tracer=tracer)
+    run.index_hbm_bytes = index_bytes(servers)
+    ctx = (coord, run, target, traffic["k"])
     with _span("bench.warm"):
         warm_up(ctx, pool, traffic)
     if tracer is not None:
@@ -315,26 +368,34 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
         queries, served = _window(ctx, pool, traffic, seconds, trace_dir)
-        memory_peak = _memory_peak(devices)
+        memory_peaks = [_memory_peak(d) for d in devices]
         if tracer is not None:
             run.spans = list(tracer.events)
         # the program's state goes before the reference runs
-        del ctx, coord, server, tracer
+        del ctx, coord, target, servers, tracer
         gc.collect()
         run.checks = check(x, queries, served, cfg)
         if trace:
-            run.trace = trace_reduce.load(_find_xplane(trace_dir))
+            xplane = _find_xplane(trace_dir)
+            run.trace = trace_reduce.load(xplane)
+            run.op_paths = scope_reduce.load_op_paths(xplane)
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": memory_peak}
+              "memory_peak_bytes": max(memory_peaks)}
+    per_device = {"memory_peak_bytes": memory_peaks,
+                  "rounds_per_batch": [
+                      sum(b.device_rounds[r] for b in run.batches)
+                      / len(run.batches)
+                      for r in range(len(run.batches[0].device_rounds))]}
     if trace:
-        busy = [d.busy_ns for d in run.trace.devices]
-        device["busy_s"] = float(np.mean(busy)) / 1e9 if busy else 0.0
+        busy = [d.busy_ns / 1e9 for d in run.trace.devices]
+        device["busy_s"] = float(np.mean(busy)) if busy else 0.0
         device["window_s"] = run.trace.window_ns / 1e9
+        per_device["busy_s"] = busy
     metrics = {}
     for m in cell_metrics(spec, cell_name,
                           "per_layer" if trace else "end_to_end"):
@@ -352,6 +413,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
                            trace_reduce.top_ops(run.trace, 10)],
             "idle_gaps": [[n, s] for n, s in
                           trace_reduce.idle_gaps(run.trace)[:10]]}
+    out["per_device"] = per_device
     out["checks"] = run.checks
     return out
 

@@ -27,6 +27,11 @@ import trace_reduce
 
 OP_PATH_STAT = "tf_op"
 
+# the search's named stages (``jax.named_scope``s of the program's
+# ``core/device_search``), as the op paths carry them
+STAGES = ("search_entry", "round_pick", "round_fetch", "round_results",
+          "round_expand", "round_route", "round_candidates")
+
 
 def _varint(buf: bytes, pos: int) -> Tuple[int, int]:
     out = shift = 0
@@ -141,3 +146,23 @@ def stage_ns(summary: trace_reduce.TraceSummary,
         out.append(dict(tot))
     return out
 
+
+def stage_ms(run, stages: Sequence[str], per_round: bool = True):
+    """The largest over devices of the window's device self time (ms)
+    under ``stages``, each op counted under the first of ``STAGES`` on
+    its path, per block-search round that device ran (``per_round``)
+    or per batch; None where no op is under them."""
+    if run.trace is None or not run.op_paths or not run.batches:
+        return None
+    out = []
+    by_device = stage_ns(run.trace, run.op_paths, STAGES)
+    for d, by_stage in enumerate(by_device):
+        if not any(s in by_stage for s in stages):
+            continue
+        count = (sum(b.device_rounds[d] for b in run.batches
+                     if d < len(b.device_rounds))
+                 if per_round else len(run.batches))
+        if count:
+            out.append(sum(by_stage.get(s, 0.0) for s in stages)
+                       / 1e6 / count)
+    return max(out) if out else None

@@ -4,6 +4,7 @@ readers: on the recorded v5e trace of a program without named stages
 (``staged.xplane.pb.gz``: the same two searches of 8 queries over a
 2,000-vector segment, 15 rounds each, with the traced server), and on
 hand counts."""
+import dataclasses
 import gzip
 import importlib.util
 import os
@@ -16,8 +17,7 @@ import tiny
 import trace_reduce
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-STAGES = ("search_entry", "round_pick", "round_fetch", "round_results",
-          "round_expand", "round_route", "round_candidates")
+STAGES = scope_reduce.STAGES
 ADC = "fusion(f32[8,16,256], s32["     # the PQ-ADC lookup's signature
 
 
@@ -135,6 +135,88 @@ def test_stage_ns_hand_count():
         {"round_route": 4e6, "round_results": 2.5e6, "search_entry": 8e5}]
     assert scope_reduce.stage_ns(summary, paths, ("round_results",)) == [
         {"round_results": 2.5e6}]
+
+
+def _stage_run(rounds, batches=2):
+    """A run on two devices whose ops fall under the stages: per device
+    d, route (2d+1) * 6 ms, candidates 3 ms, results 1 ms, fetch 0.5 ms,
+    entry 0.8 ms, and 7 ms under no stage; ``rounds`` per device per
+    batch."""
+    devs, paths = [], {}
+    for d in range(2):
+        ops = {"route": ("jit(f)/while/body/round_route/gather",
+                         (2 * d + 1) * 6e6),
+               "cand": ("jit(f)/while/body/round_candidates/sort", 3e6),
+               "res": ("jit(f)/while/body/round_results/sort", 1e6),
+               "fetch": ("jit(f)/while/body/round_fetch/gather_blocks/"
+                         "pallas_call", 5e5),
+               "entry": ("jit(f)/search_entry/while/body/gather", 8e5),
+               "loop": ("jit(f)/while", 7e6)}
+        dev = trace_reduce.DeviceTrace(
+            name=f"/device:TPU:{d}", busy_ns=0,
+            self_ns={op: t for op, (_, t) in ops.items()}, count={},
+            intervals=[])
+        devs.append(dev)
+        paths[dev.name] = {op: p for op, (p, _) in ops.items()}
+    return harness.Run(
+        config={}, op_paths=paths,
+        trace=trace_reduce.TraceSummary(window=(0, 1), devices=devs,
+                                        host_spans=[]),
+        batches=[harness.Batch(0, 1, 8, 8, list(rounds), 0)
+                 for _ in range(batches)])
+
+
+@pytest.mark.parametrize("metric,want", [
+    # the largest over devices of the stage's ms per round it ran
+    ("route_ms_per_round.closed", max(6 / 20, 18 / 40)),
+    ("merge_ms_per_round.closed", max(4 / 20, 4 / 40)),
+    ("fetch_ms_per_round.closed", 0.5 / 20),
+    ("entry_ms_per_batch.closed", 0.8 / 2),
+])
+def test_stage_readers_hand_count(metric, want):
+    read = harness.load_reader(tiny.ROOT, metric)
+    assert read(_stage_run([10, 20])) == pytest.approx(want, rel=1e-12)
+    # the device that ran more rounds is read per its own rounds
+    assert read(_stage_run([20, 10])) >= read(_stage_run([10, 20]))
+
+
+@pytest.mark.parametrize("metric", [
+    "route_ms_per_round.closed", "merge_ms_per_round.closed",
+    "expand_ms_per_round.closed", "fetch_ms_per_round.closed",
+    "entry_ms_per_batch.closed"])
+def test_stage_readers_read_nothing_without_their_stage(metric):
+    """No op under the stage, no op paths, or no trace: no reading,
+    not a zero."""
+    read = harness.load_reader(tiny.ROOT, metric)
+    run = _stage_run([10, 20])
+    run.op_paths = {d: {op: "jit(f)/while" for op in ops}
+                    for d, ops in run.op_paths.items()}
+    assert read(run) is None
+    assert read(dataclasses.replace(_stage_run([10, 20]),
+                                    op_paths={})) is None
+    assert read(dataclasses.replace(_stage_run([10, 20]),
+                                    trace=None)) is None
+    if metric.startswith("expand"):
+        assert read(_stage_run([10, 20])) is None
+
+
+def test_stage_readers_on_the_recorded_trace(staged):
+    """On the recorded chip trace (two batches of 15 rounds), each stage
+    reader reads the stage's self time over the rounds or batches."""
+    summary, paths = staged
+    run = harness.Run(config={}, trace=summary, op_paths=paths,
+                      batches=[harness.Batch(0, 1, 8, 8, [15], 0)] * 2)
+    by_stage, = scope_reduce.stage_ns(summary, paths, STAGES)
+    for metric, stages, per in [
+            ("route_ms_per_round.closed", ["round_route"], 30),
+            ("merge_ms_per_round.closed",
+             ["round_candidates", "round_results"], 30),
+            ("expand_ms_per_round.closed", ["round_expand"], 30),
+            ("fetch_ms_per_round.closed", ["round_fetch"], 30),
+            ("entry_ms_per_batch.closed", ["search_entry"], 2)]:
+        want = sum(by_stage[s] for s in stages) / 1e6 / per
+        got = harness.load_reader(tiny.ROOT, metric)(run)
+        assert got == pytest.approx(want, rel=1e-12) and got > 0, metric
 
 
 def _span(name, dur_us, **args):
