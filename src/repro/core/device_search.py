@@ -94,6 +94,14 @@ STAGES = ("search_entry",      # ADC LUT, entry points, first merge
           "round_route",       # neighbour rows, PQ-ADC routing
           "round_candidates")  # merge routed neighbours, open keys
 
+# The mesh step's stages (``make_search_step`` and the
+# ``serving/router`` step), outside ``device_anns``: the exchange of
+# every rank's top-k between chips, and their merge. ``shard_merge``
+# sits inside ``merge_shard_topk``, which only the mesh steps call. The
+# benchmark's router metrics read these names (PERF.md §3).
+ROUTER_STAGES = ("shard_exchange",   # the all-gathers over ``model``
+                 "shard_merge")      # ``merge_shard_topk``
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -921,15 +929,16 @@ def merge_shard_topk(gids: jnp.ndarray, gd: jnp.ndarray,
     arrival order or placement (an argsort by position is NOT: moving
     a segment to another rank would reorder equal-distance ties)."""
     s, q, kk = gids.shape
-    flat_i = jnp.moveaxis(gids, 0, 1).reshape(q, s * kk)
-    flat_d = jnp.moveaxis(gd, 0, 1).reshape(q, s * kk)
-    flat_d = jnp.where(flat_i >= 0, flat_d, jnp.inf)
-    key_id = jnp.where(flat_i >= 0, flat_i,
-                       jnp.iinfo(flat_i.dtype).max)
-    # lexsort: last key is primary -> (dist, then id on ties)
-    order = jnp.lexsort((key_id, flat_d))[:, :k]
-    return (jnp.take_along_axis(flat_i, order, axis=1),
-            jnp.take_along_axis(flat_d, order, axis=1))
+    with jax.named_scope("shard_merge"):
+        flat_i = jnp.moveaxis(gids, 0, 1).reshape(q, s * kk)
+        flat_d = jnp.moveaxis(gd, 0, 1).reshape(q, s * kk)
+        flat_d = jnp.where(flat_i >= 0, flat_d, jnp.inf)
+        key_id = jnp.where(flat_i >= 0, flat_i,
+                           jnp.iinfo(flat_i.dtype).max)
+        # lexsort: last key is primary -> (dist, then id on ties)
+        order = jnp.lexsort((key_id, flat_d))[:, :k]
+        return (jnp.take_along_axis(flat_i, order, axis=1),
+                jnp.take_along_axis(flat_d, order, axis=1))
 
 
 def stack_segments(segments, sharding) -> DeviceSegment:
@@ -1047,8 +1056,9 @@ def make_search_step(mesh, rules, *,
         # merged in the shared (dist, global id) order so the result
         # is placement-invariant and bit-identical to the host
         # ``serving.merge_topk`` concat over the same shards
-        gids = jax.lax.all_gather(ids, "model")      # [S, Q, k]
-        gd = jax.lax.all_gather(dists, "model")
+        with jax.named_scope("shard_exchange"):
+            gids = jax.lax.all_gather(ids, "model")      # [S, Q, k]
+            gd = jax.lax.all_gather(dists, "model")
         s, _, kk = gids.shape
         # global id = segment rank * n_local + local id
         seg_of = jnp.arange(s, dtype=jnp.int32)[:, None, None]

@@ -32,10 +32,13 @@ the shard tree — same shapes, so the step reuses the same compiled
 executable (the mesh analogue of ``repack_tier0``'s in-place pack
 swap). A settled or balanced stream plans zero moves (idempotence).
 
-Observability: ``router.route`` spans per batch, ``coord.shard`` spans
-per rank (a per-rank row in the profiler's trace),
-``router.rebalance`` spans on firing evaluations, and ``(name,
-target="rank<r>")`` metrics through ``repro.obs``.
+Observability: ``router.route`` spans per batch (with the step call's
+jit-cache misses and XLA compiles in their ``traces`` and ``compiles``
+args), ``coord.shard`` spans per rank (a per-rank row in the
+profiler's trace), ``router.rebalance`` spans on firing evaluations,
+and ``(name, target="rank<r>")`` metrics through ``repro.obs``. The
+step's all-gathers run under the named scope ``shard_exchange`` and
+its merge under ``shard_merge`` (``device_search.ROUTER_STAGES``).
 """
 from __future__ import annotations
 
@@ -184,8 +187,9 @@ class MeshQueryRouter:
             gid = jnp.where((r.ids >= 0) & own[:, None],
                             r.ids + meta[0], -1)
             gd = jnp.where(gid >= 0, r.dists, jnp.inf)
-            gids = jax.lax.all_gather(gid, "model")      # [W, Q, k]
-            gds = jax.lax.all_gather(gd, "model")
+            with jax.named_scope("shard_exchange"):
+                gids = jax.lax.all_gather(gid, "model")  # [W, Q, k]
+                gds = jax.lax.all_gather(gd, "model")
             mi, md = merge_shard_topk(gids, gds, k)
             owni = own.astype(jnp.int32)
             col = jnp.ones((1, 1), jnp.int32)
@@ -270,12 +274,17 @@ class MeshQueryRouter:
         meta = self._rank_meta(q.shape[0])
         step = self._get_step(k)
         if self.tracer is not None:
+            from repro.obs.compiles import compile_counts
             with self.tracer.span("router.route", cat="serve",
                                   track="router",
                                   n_queries=int(q.shape[0]), k=int(k),
                                   ranks=self.world) as sp:
+                traces, compiles = compile_counts()
                 out = step(self._seg_stack, jnp.asarray(q),
                            jnp.asarray(meta))
+                traces_now, compiles_now = compile_counts()
+                sp["traces"] = traces_now - traces
+                sp["compiles"] = compiles_now - compiles
                 ids, dists, stats = self._account(out, meta)
                 sp["block_reads"] = stats["total_block_reads"]
                 sp["rounds_max"] = stats["rounds_max"]

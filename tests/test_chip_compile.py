@@ -27,10 +27,7 @@ from repro.serving.coordinator import SERVE_DEVICE_SEARCH
 N, DIM, Q = 1 << 20, 128, 256
 LAM = SEGMENT_BIGANN_F32.graph.max_degree
 EPS = SEGMENT_BIGANN_F32.layout.verts_per_block(DIM, LAM)
-RHO = -(-N // EPS)
-HOT = int(SEGMENT_BIGANN_F32.cache.tier0_frac * RHO)
 PQ_M = SEGMENT_BIGANN_F32.pq.num_subspaces
-NAV_N = int(SEGMENT_BIGANN_F32.nav.sample_ratio * N)
 NAV_DEG = SEGMENT_BIGANN_F32.nav.max_degree
 V5E_HBM = 16 * 10 ** 9
 
@@ -72,25 +69,35 @@ def _on(sharding):
                                                      sharding=sharding)
 
 
-def _segment_specs(sharding) -> DS.DeviceSegment:
-    """The shapes ``from_segment`` packs for the smoke's segment."""
+def _segment_specs(sharding, n: int = N, ranks: tuple = ()
+                   ) -> DS.DeviceSegment:
+    """The shapes ``from_segment`` packs for a segment of ``n`` vectors
+    (the smoke's by default), each behind a leading ``ranks`` shape
+    (a stack of segments, one per mesh rank, when given)."""
     s = _on(sharding)
+    rho = -(-n // EPS)
+    hot = int(SEGMENT_BIGANN_F32.cache.tier0_frac * rho)
+    nav = int(SEGMENT_BIGANN_F32.nav.sample_ratio * n)
+
+    def leaf(shape, dtype):
+        return s(ranks + shape, dtype)
+
     return DS.DeviceSegment(
-        vecs=s((RHO, EPS, DIM), jnp.float32),
-        vid=s((RHO, EPS), jnp.int32),
-        deg=s((RHO, EPS), jnp.int32),
-        nbrs=s((RHO, EPS, LAM), jnp.int32),
-        block_of=s((N,), jnp.int32),
-        pq_codes=s((N, PQ_M), jnp.uint8),
-        pq_cent=s((PQ_M, 256, DIM // PQ_M), jnp.float32),
-        nav_vecs=s((NAV_N, DIM), jnp.float32),
-        nav_adj=s((NAV_N, NAV_DEG), jnp.int32),
-        nav_ids=s((NAV_N,), jnp.int32),
-        nav_entry=s((), jnp.int32),
-        hot_vecs=s((HOT, EPS, DIM), jnp.float32),
-        hot_vid=s((HOT, EPS), jnp.int32),
-        hot_nbrs=s((HOT, EPS, LAM), jnp.int32),
-        hot_slot_of=s((RHO,), jnp.int32))
+        vecs=leaf((rho, EPS, DIM), jnp.float32),
+        vid=leaf((rho, EPS), jnp.int32),
+        deg=leaf((rho, EPS), jnp.int32),
+        nbrs=leaf((rho, EPS, LAM), jnp.int32),
+        block_of=leaf((n,), jnp.int32),
+        pq_codes=leaf((n, PQ_M), jnp.uint8),
+        pq_cent=leaf((PQ_M, 256, DIM // PQ_M), jnp.float32),
+        nav_vecs=leaf((nav, DIM), jnp.float32),
+        nav_adj=leaf((nav, NAV_DEG), jnp.int32),
+        nav_ids=leaf((nav,), jnp.int32),
+        nav_entry=leaf((), jnp.int32),
+        hot_vecs=leaf((hot, EPS, DIM), jnp.float32),
+        hot_vid=leaf((hot, EPS), jnp.int32),
+        hot_nbrs=leaf((hot, EPS, LAM), jnp.int32),
+        hot_slot_of=leaf((rho,), jnp.int32))
 
 
 @pytest.mark.parametrize("pipeline", [True, False])
@@ -183,3 +190,42 @@ def test_knn_block_compiles_without_a_full_width_top_k(one_chip):
     assert top_k and not any(f"x{N}x" in line for line in top_k)
     mem = lowered.compile().memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
+def test_router_step_compiles_for_four_chips(topo):
+    """The mesh router's step at ``seg4-router-closed``'s size (four
+    segments of 125,000 at Γ=1024, one per chip of a v5e 2x2) compiles
+    with the kernel, keeps its exchange under ``shard_exchange`` and its
+    merge under ``shard_merge``, and fits each chip's HBM with the
+    member segment the chip also holds."""
+    import types
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.serving.router import MeshQueryRouter
+    ranks, n = 4, 125_000
+    mesh = Mesh(np.asarray(topo.devices[:ranks]).reshape(1, ranks),
+                ("data", "model"))
+    stack = _segment_specs(NamedSharding(mesh, P("model")), n=n,
+                           ranks=(ranks,))
+    params = dataclasses.replace(SERVE_DEVICE_SEARCH, candidates=1024,
+                                 max_hops=4096, compact_frac=0.25)
+    # the router's own `_build_step`, without member arrays to stack
+    router = types.SimpleNamespace(mesh=mesh, world=ranks,
+                                   _seg_stack=stack,
+                                   search_params=params, metric="l2")
+    compiled = MeshQueryRouter._build_step(router, 10).lower(
+        stack, _on(NamedSharding(mesh, P()))((Q, DIM), jnp.float32),
+        _on(NamedSharding(mesh, P("model")))((ranks, 3), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for stage in DS.ROUTER_STAGES:
+        assert f"/{stage}/" in text, stage
+    mem = compiled.memory_analysis()
+    # the chip's member server keeps its own copy of the segment,
+    # about the step's argument bytes
+    member = mem.argument_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + member < V5E_HBM

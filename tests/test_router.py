@@ -4,8 +4,10 @@ rebalance.
 
 The mesh-dependent tests run in the ``make test-mesh`` lane
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) and skip on
-smaller worlds; the planning/validation tests run everywhere."""
+smaller worlds; the planning/validation tests, and those of the step on
+one rank, run everywhere."""
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -307,3 +309,83 @@ def test_router_rejects_nonmodel_sharding():
     with pytest.raises(ValueError, match="'model' only"):
         MeshQueryRouter([_Stub(p)],
                         mesh=_FakeMesh({"data": 2, "model": 2}))
+
+
+# ------------------------------------------- the step's stages, its compiles
+#
+# On one rank (a 1 x 1 mesh of this process's first device), so these
+# run in every lane: the step's exchange and merge lower the same way
+# whatever the mesh's size.
+
+def _one_rank_mesh():
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+
+
+@pytest.fixture(scope="module")
+def one_rank():
+    """One small segment's server and queries over it."""
+    p = dataclasses.replace(SERVE_DEVICE_SEARCH, candidates=24,
+                            fetch_impl="jnp")
+    x = clustered_vectors(N_PER_SEG, 32, num_clusters=8, seed=30)
+    server = SegmentServer(
+        segment=DS.from_segment(build_segment(x, SMALL_SEGMENT)),
+        offset=0, num_vectors=x.shape[0], params=p)
+    return server, query_set(x, 8, seed=7)
+
+
+def _op_names(compiled_text: str, op: str):
+    """The ``op_name`` metadata of every ``op`` instruction."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in compiled_text.splitlines()
+            if f" {op}(" in line and "op_name=" in line]
+
+
+def _assert_stages_named(compiled_text: str) -> None:
+    gathers = _op_names(compiled_text, "all-gather")
+    assert gathers and all("/shard_exchange/" in n for n in gathers), \
+        gathers
+    assert any("/shard_merge/" in n
+               for n in _op_names(compiled_text, "sort"))
+
+
+def test_router_step_names_its_exchange_and_merge(one_rank):
+    """The router's all-gathers carry ``shard_exchange`` and its merge
+    ``shard_merge`` in their HLO ``op_name``: what the trace's op paths
+    read per stage."""
+    server, q = one_rank
+    router = MeshQueryRouter([server], mesh=_one_rank_mesh())
+    meta = router._rank_meta(q.shape[0])
+    compiled = router._get_step(10).lower(
+        router._seg_stack, jax.numpy.asarray(q),
+        jax.numpy.asarray(meta)).compile()
+    _assert_stages_named(compiled.as_text())
+
+
+def test_search_step_names_its_exchange_and_merge():
+    from repro.core.params import DeviceSearchParams
+    from repro.distributed.sharding import SEGMENT_SERVE_RULES
+    fn, args = DS.make_search_step(
+        _one_rank_mesh(), SEGMENT_SERVE_RULES, n_local=1 << 10, dim=32,
+        eps=4, lam=8, q_global=8, pq_m=4, nav_frac=16, nav_deg=4,
+        search=DeviceSearchParams(candidates=16, max_hops=16,
+                                  fetch_impl="jnp"))
+    _assert_stages_named(jax.jit(fn).lower(*args).compile().as_text())
+
+
+def test_route_span_counts_the_step_compiles(one_rank):
+    """``router.route``'s ``traces`` counts the step's jit-cache misses:
+    one or more on the first batch of a new k, none on the next."""
+    from repro.obs.trace import Tracer
+    server, q = one_rank
+    tracer = Tracer()
+    router = MeshQueryRouter([server], mesh=_one_rank_mesh(),
+                             tracer=tracer)
+    for k in (10, 10, 5, 5):
+        router.route(q, k=k)
+    spans = [e for e in tracer.events if e.name == "router.route"]
+    traces = [e.args["traces"] for e in spans]
+    assert traces[0] >= 1 and traces[1] == 0, traces
+    assert traces[2] >= 1 and traces[3] == 0, traces
+    assert all(e.args["compiles"] >= 0 for e in spans)
